@@ -41,6 +41,8 @@ def main(argv=None) -> None:
             sys.exit(2)
         modules = tuple(selected)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod in modules:

@@ -45,8 +45,18 @@ from .scheduler import (ConvCarry, LayerStats, init_conv_carry,
                         run_conv_layer_batched_chunk,
                         run_conv_layer_batched_chunk_streamed,
                         run_conv_layer_batched_planned, run_conv_layer_dense,
-                        run_conv_layer_planned, run_fc_head,
+                        run_conv_layer_planned, head_dot, run_fc_head,
                         run_fc_head_batched)
+
+
+# Logits of one sample agree across batch shapes (engine slot tables,
+# tile padding, shards) and with the float32 reference to this tolerance,
+# not bit for bit: the head contracts exact spike counts with float32
+# weights, and the dot's reduction order depends on the operand shape.
+# Spike counts agree exactly.  chip_smoke.py and the serving tests hold
+# every path to it.
+LOGITS_RTOL = 1e-5
+LOGITS_ATOL = 1e-5
 
 
 @dataclass(frozen=True)
@@ -315,7 +325,7 @@ def snn_readout(params: dict, state: CSNNState, cfg: CSNNConfig,
                 logits = (event_readout(drive, p["w"], capacity=fc_capacity)
                           + cfg.t_steps * p["b"])
             else:
-                logits = drive @ p["w"] + cfg.t_steps * p["b"]
+                logits = head_dot(drive, p["w"]) + cfg.t_steps * p["b"]
     if logits is None:
         raise ValueError("cfg has no FC head layer")
     return logits
@@ -450,7 +460,6 @@ def snn_apply_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.sharding.specs import batch_mesh
 
     plan = _resolve_plan(cfg, plan, capacity, channel_block, sat_bits)
@@ -473,8 +482,8 @@ def snn_apply_sharded(
                  [LayerStats(P(axis), P(axis), P(axis), P(), P())] * n_conv)
     # check_vma off: per-shard constants (event_block) come back replicated
     # from device-varying inputs, which strict vma tracking rejects.
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
+                       out_specs=out_specs, check_vma=False)
     x, stats = fn(params, in_spikes)
     # Gather the (still batch-sharded) spike maps onto one device before
     # the head: a dot over a row-sharded operand would run one-row-per-

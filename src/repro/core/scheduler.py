@@ -308,7 +308,8 @@ def run_conv_layer_dense(
         x = x_t.astype(vm_dtype)[None]  # (1, H, W, C_in)
         u = jax.lax.conv_general_dilated(
             x, kernels.astype(vm_dtype), (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)[0]
         vm = vm + u + bias.astype(vm_dtype)
         spikes = (vm > jnp.asarray(v_t, vm_dtype)) | fired
         return (vm, spikes), spikes
@@ -683,6 +684,15 @@ def _run_chunk_from_events(
     return spikes_out, new_carry, stats
 
 
+def head_dot(drive: jax.Array, weights: jax.Array) -> jax.Array:
+    """The classification unit's contraction, drive @ weights, at full
+    float32 precision.  Named explicitly because a TPU runs a float32 dot
+    at the default precision as bf16 passes, which would round the
+    trained weights; every head (served, sparse, reference) goes through
+    here."""
+    return jnp.dot(drive, weights, precision=jax.lax.Precision.HIGHEST)
+
+
 def run_fc_head(spikes_in: jax.Array, weights: jax.Array, bias: jax.Array,
                 capacity: Optional[int] = None) -> jax.Array:
     """Classification unit (paper Sec. V-A): integrate-only FC readout.
@@ -702,7 +712,7 @@ def run_fc_head(spikes_in: jax.Array, weights: jax.Array, bias: jax.Array,
         from .sparse_ffn import event_readout
         return event_readout(drive, weights,
                              capacity=capacity) + t_steps * bias
-    return drive @ weights + t_steps * bias
+    return head_dot(drive, weights) + t_steps * bias
 
 
 def run_fc_head_batched(spikes_in: jax.Array, weights: jax.Array,
@@ -722,4 +732,4 @@ def run_fc_head_batched(spikes_in: jax.Array, weights: jax.Array,
         from .sparse_ffn import event_readout
         return event_readout(drive, weights,
                              capacity=capacity) + t_steps * bias
-    return drive @ weights + t_steps * bias
+    return head_dot(drive, weights) + t_steps * bias

@@ -89,7 +89,8 @@ def event_readout(drive: jax.Array, weights: jax.Array, *,
     vals, idx = jax.lax.top_k(flat, capacity)        # the head's AEQ
     rows = jnp.arange(flat.shape[0])[:, None]
     compact = jnp.zeros_like(flat).at[rows, idx].set(vals)
-    return (compact.reshape(drive.shape) @ weights)
+    from .scheduler import head_dot
+    return head_dot(compact.reshape(drive.shape), weights)
 
 
 def drive_active_counts(drive: jax.Array) -> jax.Array:

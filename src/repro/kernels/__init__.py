@@ -29,9 +29,10 @@ tests sweep against).
 Both are wired into the Algorithm-1 scheduler via
 core.scheduler.run_conv_layer*(backend="pallas").
 
-Interpret mode is a single switch (``kernels.runtime.resolve_interpret``):
-every wrapper defaults to ``interpret=None``, which resolves from the
-REPRO_PALLAS_INTERPRET env var, else to interpret-on unless the default
-backend is a real TPU — so validating on hardware is a one-line flip
-(``REPRO_PALLAS_INTERPRET=0``) instead of an every-call-site edit.
+Interpret mode is one rule (``kernels.runtime.resolve_interpret``):
+every wrapper defaults to ``interpret=None``, which compiles on a TPU
+backend and interprets everywhere else; an explicit ``interpret=`` wins.
+Every entry point compiles for TPU v5e at the paper network's served
+shapes (``tests/test_tpu_compile.py``, ahead of time) and runs bit-exact
+against its ``ref.py`` oracle on the chip (``chip_smoke.py``).
 """

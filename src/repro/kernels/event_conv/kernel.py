@@ -11,11 +11,19 @@ Maps the FPGA convolution unit onto the TPU memory hierarchy:
   thresholded).  The kernel window is derived from the weight shape, so
   every entry point serves any odd k x k geometry with one code path.
 * The grid runs over **event blocks**; each step streams one block of
-  queue entries (coords, valid) from HBM while vm stays put
-  (``input_output_aliases`` accumulates in place across grid steps) —
-  the analogue of the AEQ feeding the pipeline a steady event stream.
+  queue entries from HBM into SMEM (the scalar core reads the addresses)
+  while vm stays put (``input_output_aliases`` accumulates in place across
+  grid steps) — the analogue of the AEQ feeding the pipeline a steady
+  event stream.  An entry is one packed int32 word, ``i << 16 | j`` for a
+  valid event and -1 for an invalid slot (``_pack_events``).
 * Parallelism is over the **C output channels in the lane dimension**
   (the TPU-native replacement for the FPGA's 9 tap-parallel PEs).
+* An event updates a **row band**: the kh full rows ``vm[i:i+kh]`` are
+  read, the (already 180deg-rotated) taps are placed at columns
+  ``j..j+kw-1`` by an iota compare, and the band is written back.  Only
+  the leading (untiled) axis is indexed dynamically; cells outside the
+  window receive an exact zero, so the band update equals the window
+  update bit for bit.
 * Integer dtypes use saturating adds (paper C7): the accumulation is
   widened to int32 and clamped back to the storage width.
 
@@ -28,24 +36,18 @@ Two schedules per entry point:
   ``_batched``): each grid step walks groups of ``event_par`` consecutive
   queue slots.  The AEQ emits events in interlace-column order
   (s = kw*(i%kh)+(j%kw)), and same-column events are >= kh apart in i or
-  >= kw apart in j, so their window patches are DISJOINT: a
-  column-homogeneous group is applied
-  as one vectorized gather -> add -> scatter (all patch reads complete
-  before any write; disjoint writes never reorder a single cell's
+  >= kw apart in j, so their windows are DISJOINT: a column-homogeneous
+  group reads all its bands, adds, and writes each window back (a masked
+  select over the band, so a neighbour's window in the same rows keeps
+  its own update).  Disjoint windows never reorder a single cell's
   accumulation, so the result is bit-exact vs the sequential kernel —
-  saturating int paths included, since a cell sees at most one event per
-  group).  A group that straddles a column boundary falls back to the
-  sequential body for just that group.  Feeding the kernel a
-  segment-padded queue (``aeq.segment_pad``; what the ops wrapper and the
-  planned scheduler do) makes every group homogeneous by construction, so
-  the fallback never fires and the serial dependence chain only remains
-  *across* groups.  Invalid slots are replayed as copies of the group's
-  first valid event — they re-write the identical updated patch, which is
-  idempotent under the all-reads-first schedule.
-
-Block shapes: the C axis should be a multiple of 128 (lane width) and the
-vm tile must fit VMEM: (H+2)(W+2)*C*4B; for the paper's 28x28 layers with
-C=128 that is ~0.46 MB — comfortable against ~16 MB VMEM.
+  saturating int paths included.  A group that straddles a column
+  boundary falls back to the sequential body for just that group.
+  Feeding the kernel a segment-padded queue (``aeq.segment_pad``; what
+  the ops wrapper and the planned scheduler do) makes every group
+  homogeneous by construction.  Invalid slots are replayed as copies of
+  the group's first valid event — they re-write the identical updated
+  window, which is idempotent under the all-reads-first schedule.
 
 Batched variants run a 2-D grid over (queue, event block): one
 ``pallas_call`` streams every queue's events against its own
@@ -53,7 +55,7 @@ VMEM-resident vm tile.  The event-block axis is innermost, so each
 queue's tile is loaded once and revisited until its stream is exhausted.
 
 Interpret mode is resolved by ``kernels.runtime.resolve_interpret``
-(REPRO_PALLAS_INTERPRET env var; defaults on off-TPU).
+(compiled on a TPU backend, interpreted elsewhere).
 """
 from __future__ import annotations
 
@@ -62,76 +64,116 @@ from functools import partial, reduce
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
 _SAT_RANGE = {jnp.int8.dtype: (-128, 127), jnp.int16.dtype: (-32768, 32767)}
 
 
+def _pack_events(coords: jax.Array, valid: jax.Array) -> jax.Array:
+    """(..., E, 2) int32 addresses + (..., E) valid bits -> (..., E) int32
+    words, ``i << 16 | j`` where valid and -1 where not — the one scalar
+    the kernel's SMEM event stream carries per queue slot."""
+    word = coords[..., 0] * 65536 + coords[..., 1]
+    return jnp.where(valid != 0, word, -1).astype(jnp.int32)
+
+
 def _acc_patch(patch, contrib, dtype):
+    """patch + contrib in the compute dtype (int32 for the narrow int
+    datapaths, which saturate at the storage width — the fixed-point PE
+    adders of paper C7)."""
     sat = _SAT_RANGE.get(dtype)
-    if sat is not None:  # saturating fixed-point PE adders (paper C7)
-        wide = patch.astype(jnp.int32) + contrib.astype(jnp.int32)
-        return jnp.clip(wide, sat[0], sat[1]).astype(dtype)
+    if sat is not None:
+        return jnp.clip(patch + contrib, sat[0], sat[1])
     return patch + contrib
 
 
-def _apply_event_block(coords_ref, valid_ref, kernel_ref, out_ref, *,
-                       block_e, prefix=()):
-    """Apply ``block_e`` queue entries to the VMEM-resident vm tile.
+class _Unit:
+    """Per-grid-step view of the conv unit: the event block, the rotated
+    taps and the vm tile of the current queue (``prefix`` selects the
+    tile inside the block — (0,) for the batched kernels' (1, ...)
+    blocks)."""
 
-    Shared body of the 1-D and 2-D grid kernels — ``prefix`` is the
-    leading ref index selecting the current queue's block ((0,) for the
-    batched kernel's (1, ...) blocks, () for the single-queue kernel).
-    vm arrives through out_ref thanks to input_output_aliases: every grid
-    step accumulates into the same tile.
-    """
-    k_rot = kernel_ref[...][::-1, ::-1, :]  # 180deg rotation (paper Fig. 4)
-    kh, kw = k_rot.shape[:2]                # window from the weight shape
-    zero = jnp.zeros_like(k_rot)
+    def __init__(self, ev_ref, kernel_ref, out_ref, prefix):
+        self.ev_ref, self.out_ref, self.prefix = ev_ref, out_ref, prefix
+        self.dtype = out_ref.dtype
+        # narrow ints are widened for the band math: Mosaic selects and
+        # adds on packed int8/int16 vectors do not lower
+        self.wide = (jnp.int32 if self.dtype in _SAT_RANGE else self.dtype)
+        k = kernel_ref[...].astype(self.wide)    # (kh, kw, C), rotated
+        self.kh, self.kw = k.shape[:2]
+        self.taps = [k[:, b:b + 1, :] for b in range(self.kw)]
+        self.band_shape = (self.kh,) + tuple(out_ref.shape[-2:])
+        self.col = jax.lax.broadcasted_iota(jnp.int32, self.band_shape, 1)
+
+    def event(self, e):
+        """(i, j, valid) of slot ``e``; invalid slots address (0, 0)."""
+        word = self.ev_ref[0, 0, e]
+        v = word >= 0
+        return (jnp.where(v, word >> 16, 0), jnp.where(v, word & 0xFFFF, 0),
+                v)
+
+    def band(self, i):
+        return self.prefix + (pl.ds(i, self.kh), slice(None), slice(None))
+
+    def read(self, i):
+        return self.out_ref[self.band(i)].astype(self.wide)
+
+    def write(self, i, band):
+        self.out_ref[self.band(i)] = band.astype(self.dtype)
+
+    def window(self, j):
+        return (self.col >= j) & (self.col < j + self.kw)
+
+    def contrib(self, j, on):
+        """The taps placed at columns j..j+kw-1 of a row band (zeros
+        elsewhere, and everywhere when ``on`` is False)."""
+        c = jnp.zeros(self.band_shape, self.wide)
+        for b, tap in enumerate(self.taps):
+            c = jnp.where(self.col == j + b, tap, c)
+        return jnp.where(on, c, jnp.zeros_like(c))
+
+    def apply(self, i, j, v):
+        self.write(i, _acc_patch(self.read(i), self.contrib(j, v),
+                                 self.dtype))
+
+
+def _sequential_kernel(ev_ref, kernel_ref, vm_ref, out_ref, *, block_e,
+                       prefix):
+    """One grid step: apply ``block_e`` queue entries in program order.
+    vm arrives through out_ref thanks to input_output_aliases, so every
+    grid step accumulates into the same tile."""
+    unit = _Unit(ev_ref, kernel_ref, out_ref, prefix)
 
     def body(e, _):
-        i = coords_ref[prefix + (e, 0)]
-        j = coords_ref[prefix + (e, 1)]
-        v = valid_ref[prefix + (e,)] != 0
-        # Invalid slots contribute zeros at the (0,0) corner — branch-free
-        # masking, the AEQ valid bit in vector form.
-        i = jnp.where(v, i, 0)
-        j = jnp.where(v, j, 0)
-        contrib = jnp.where(v, k_rot, zero)
-        idx = prefix + (pl.dslice(i, kh), pl.dslice(j, kw), slice(None))
-        out_ref[idx] = _acc_patch(out_ref[idx], contrib, out_ref.dtype)
+        unit.apply(*unit.event(e))
         return ()
 
     jax.lax.fori_loop(0, block_e, body, ())
 
 
-def _apply_event_block_interlaced(coords_ref, valid_ref, kernel_ref, out_ref,
-                                  *, block_e, event_par, prefix=()):
+def _interlaced_kernel(ev_ref, kernel_ref, vm_ref, out_ref, *, block_e,
+                       event_par, prefix):
     """Apply ``block_e`` entries as ``event_par``-wide hazard-free groups.
 
     Per group: read the slots' (i, j, valid); pick the first valid event
     as the group anchor; if every valid slot shares the anchor's interlace
-    column (always true on segment-padded queues), gather all patches,
-    add, and scatter — reads complete before writes, and same-column
-    disjointness makes the writes conflict-free.  Invalid slots replay the
-    anchor (same patch, same contribution — an idempotent duplicate
-    write); a group with no valid slots degenerates to writing the (0,0)
-    patch back unchanged.  Otherwise fall back to the sequential body for
-    this group only (the column-boundary case on unpadded queues).
+    column (always true on segment-padded queues), read all bands, add,
+    and write each window back — reads complete before writes, and
+    same-column disjointness makes the writes conflict-free.  Invalid
+    slots replay the anchor (same window, same contribution — an
+    idempotent duplicate write); a group with no valid slots writes the
+    (0,0) window back unchanged.  Otherwise fall back to the sequential
+    body for this group only (the column-boundary case on unpadded
+    queues).
     """
-    k_rot = kernel_ref[...][::-1, ::-1, :]
-    kh, kw = k_rot.shape[:2]                # window from the weight shape
-    zero = jnp.zeros_like(k_rot)
-    n_groups = block_e // event_par
+    unit = _Unit(ev_ref, kernel_ref, out_ref, prefix)
+    kh, kw = unit.kh, unit.kw
 
     def group(g, _):
         base = g * event_par
-        ii, jj, vv = [], [], []
-        for p in range(event_par):
-            ii.append(coords_ref[prefix + (base + p, 0)])
-            jj.append(coords_ref[prefix + (base + p, 1)])
-            vv.append(valid_ref[prefix + (base + p,)] != 0)
+        ii, jj, vv = zip(*(unit.event(base + p) for p in range(event_par)))
         cols = [(i % kh) * kw + (j % kw) for i, j in zip(ii, jj)]
         # first-valid anchor (coords + column); zeros when the group is empty
         zero_i = jnp.zeros_like(ii[0])
@@ -145,41 +187,92 @@ def _apply_event_block_interlaced(coords_ref, valid_ref, kernel_ref, out_ref,
         homog = reduce(jnp.logical_and,
                        [~vv[p] | (cols[p] == acol) for p in range(event_par)])
 
-        def patch_idx(i, j):
-            return prefix + (pl.dslice(i, kh), pl.dslice(j, kw), slice(None))
-
         @pl.when(homog)
         def _parallel():
             mi = [jnp.where(vv[p], ii[p], ai) for p in range(event_par)]
             mj = [jnp.where(vv[p], jj[p], aj) for p in range(event_par)]
-            contrib = [jnp.where(vv[p] | found, k_rot, zero)
-                       for p in range(event_par)]
-            patches = [out_ref[patch_idx(mi[p], mj[p])]
-                       for p in range(event_par)]                 # gather
-            updated = [_acc_patch(patches[p], contrib[p], out_ref.dtype)
+            bands = [unit.read(mi[p]) for p in range(event_par)]  # gather
+            updated = [_acc_patch(bands[p], unit.contrib(mj[p], found),
+                                  unit.dtype)
                        for p in range(event_par)]                 # add
             for p in range(event_par):                            # scatter
-                out_ref[patch_idx(mi[p], mj[p])] = updated[p]
+                unit.write(mi[p], jnp.where(unit.window(mj[p]), updated[p],
+                                            unit.read(mi[p])))
 
         @pl.when(~homog)
         def _sequential():
             for p in range(event_par):
-                i = jnp.where(vv[p], ii[p], 0)
-                j = jnp.where(vv[p], jj[p], 0)
-                contrib = jnp.where(vv[p], k_rot, zero)
-                idx = patch_idx(i, j)
-                out_ref[idx] = _acc_patch(out_ref[idx], contrib,
-                                          out_ref.dtype)
+                unit.apply(ii[p], jj[p], vv[p])
 
         return ()
 
-    jax.lax.fori_loop(0, n_groups, group, ())
+    jax.lax.fori_loop(0, block_e // event_par, group, ())
 
 
-def _event_conv_kernel(coords_ref, valid_ref, kernel_ref, vm_ref, out_ref, *, block_e):
-    """One grid step: apply ``block_e`` queue entries to the VMEM vm tile."""
-    _apply_event_block(coords_ref, valid_ref, kernel_ref, out_ref,
-                       block_e=block_e)
+def _conv_call(body, vm_padded, coords, valid, kernel, *, block_e,
+               interpret):
+    """One pallas_call over a (queue, event block) grid — a 1-D event-block
+    grid when ``vm_padded`` is a single (Hp, Wp, C) tile."""
+    batched = vm_padded.ndim == 4
+    hp, wp, c = vm_padded.shape[-3:]
+    kh, kw = kernel.shape[:2]
+    words = _pack_events(coords, valid)
+    e = words.shape[-1]
+    n_blk = e // block_e
+    if batched:
+        q = vm_padded.shape[0]
+        if words.shape[0] != q:
+            raise ValueError(
+                f"queue count mismatch: vm has {q} tiles, coords describe "
+                f"{words.shape[0]} queues")
+        # (Q * n_blk, 1, block_e): each block's trailing dims equal the
+        # array's, the layout SMEM blocks need
+        words = words.reshape(q * n_blk, 1, block_e)
+        grid = (q, n_blk)
+        ev_spec = pl.BlockSpec((1, 1, block_e),
+                               lambda qi, b: (qi * n_blk + b, 0, 0),
+                               memory_space=pltpu.SMEM)
+        k_spec = pl.BlockSpec((kh, kw, c), lambda qi, b: (0, 0, 0))
+        vm_spec = pl.BlockSpec((1, hp, wp, c), lambda qi, b: (qi, 0, 0, 0))
+        prefix = (0,)
+    else:
+        words = words.reshape(n_blk, 1, block_e)
+        grid = (n_blk,)
+        ev_spec = pl.BlockSpec((1, 1, block_e), lambda b: (b, 0, 0),
+                               memory_space=pltpu.SMEM)
+        k_spec = pl.BlockSpec((kh, kw, c), lambda b: (0, 0, 0))
+        vm_spec = pl.BlockSpec((hp, wp, c), lambda b: (0, 0, 0))
+        prefix = ()
+    return pl.pallas_call(
+        partial(body, block_e=block_e, prefix=prefix),
+        grid=grid,
+        in_specs=[ev_spec, k_spec, vm_spec],
+        out_specs=vm_spec,
+        out_shape=jax.ShapeDtypeStruct(vm_padded.shape, vm_padded.dtype),
+        input_output_aliases={2: 0},  # accumulate vm in place across steps
+        interpret=resolve_interpret(interpret),
+    )(words, kernel[::-1, ::-1, :], vm_padded)  # 180deg rotation (Fig. 4)
+
+
+def _check_blocks(e: int, block_e: int) -> None:
+    if e % block_e != 0:
+        raise ValueError(
+            f"event stream length E={e} must be a multiple of "
+            f"block_e={block_e}: the grid tiles the queue evenly — go "
+            f"through the ops.py wrappers, which pad the queue for you")
+
+
+def _check_interlaced_blocks(e: int, block_e: int, event_par: int) -> None:
+    if event_par < 2:
+        raise ValueError(
+            f"event_par={event_par}: the interlaced kernel needs >= 2 "
+            f"events per group (use event_conv_pallas for the sequential "
+            f"schedule)")
+    if block_e % event_par != 0:
+        raise ValueError(
+            f"block_e={block_e} must be a multiple of event_par="
+            f"{event_par} so parallel groups tile each event block")
+    _check_blocks(e, block_e)
 
 
 @partial(jax.jit, static_argnames=("block_e", "interpret"))
@@ -204,37 +297,9 @@ def event_conv_pallas(
     Returns the updated (H+2hh, W+2hw, C) tile.  E is padded up to a
     multiple of ``block_e`` by the wrapper in ops.py.
     """
-    e = coords.shape[0]
-    if e % block_e != 0:
-        raise ValueError(
-            f"event stream length E={e} must be a multiple of "
-            f"block_e={block_e}: the grid tiles the queue evenly — go "
-            f"through the ops.py wrappers, which pad the queue for you")
-    hp, wp, c = vm_padded.shape
-    kh, kw = kernel.shape[:2]
-    grid = (e // block_e,)
-    return pl.pallas_call(
-        partial(_event_conv_kernel, block_e=block_e),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, 2), lambda b: (b, 0)),      # event coords stream
-            pl.BlockSpec((block_e,), lambda b: (b,)),           # valid bits stream
-            pl.BlockSpec((kh, kw, c), lambda b: (0, 0, 0)),     # kernel, resident
-            pl.BlockSpec((hp, wp, c), lambda b: (0, 0, 0)),     # vm, resident
-        ],
-        out_specs=pl.BlockSpec((hp, wp, c), lambda b: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((hp, wp, c), vm_padded.dtype),
-        input_output_aliases={3: 0},  # accumulate vm in place across grid steps
-        interpret=resolve_interpret(interpret),
-    )(coords, valid.astype(jnp.int8), kernel, vm_padded)
-
-
-def _event_conv_batched_kernel(coords_ref, valid_ref, kernel_ref, vm_ref,
-                               out_ref, *, block_e):
-    """One (queue, event-block) grid step: apply ``block_e`` entries of the
-    current queue to that queue's VMEM-resident vm tile."""
-    _apply_event_block(coords_ref, valid_ref, kernel_ref, out_ref,
-                       block_e=block_e, prefix=(0,))
+    _check_blocks(coords.shape[0], block_e)
+    return _conv_call(_sequential_kernel, vm_padded, coords, valid, kernel,
+                      block_e=block_e, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_e", "interpret"))
@@ -261,56 +326,9 @@ def event_conv_pallas_batched(
     tiles; per-queue program order is preserved exactly, so results are
     bit-identical to Q sequential ``event_conv_pallas`` calls.
     """
-    q, e, _ = coords.shape
-    if e % block_e != 0:
-        raise ValueError(
-            f"event stream length E={e} must be a multiple of "
-            f"block_e={block_e}: the grid tiles the queue evenly — go "
-            f"through the ops.py wrappers, which pad the queues for you")
-    if vm_padded.shape[0] != q:
-        raise ValueError(
-            f"queue count mismatch: vm has {vm_padded.shape[0]} tiles, "
-            f"coords describe {q} queues")
-    _, hp, wp, c = vm_padded.shape
-    kh, kw = kernel.shape[:2]
-    grid = (q, e // block_e)
-    return pl.pallas_call(
-        partial(_event_conv_batched_kernel, block_e=block_e),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_e, 2), lambda qi, b: (qi, b, 0)),  # event stream
-            pl.BlockSpec((1, block_e), lambda qi, b: (qi, b)),         # valid bits
-            pl.BlockSpec((kh, kw, c), lambda qi, b: (0, 0, 0)),        # kernel, resident
-            pl.BlockSpec((1, hp, wp, c), lambda qi, b: (qi, 0, 0, 0)),  # vm tile
-        ],
-        out_specs=pl.BlockSpec((1, hp, wp, c), lambda qi, b: (qi, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, hp, wp, c), vm_padded.dtype),
-        input_output_aliases={3: 0},  # accumulate each tile in place
-        interpret=resolve_interpret(interpret),
-    )(coords, valid.astype(jnp.int8), kernel, vm_padded)
-
-
-def _check_interlaced_blocks(e: int, block_e: int, event_par: int) -> None:
-    if event_par < 2:
-        raise ValueError(
-            f"event_par={event_par}: the interlaced kernel needs >= 2 "
-            f"events per group (use event_conv_pallas for the sequential "
-            f"schedule)")
-    if block_e % event_par != 0:
-        raise ValueError(
-            f"block_e={block_e} must be a multiple of event_par="
-            f"{event_par} so parallel groups tile each event block")
-    if e % block_e != 0:
-        raise ValueError(
-            f"event stream length E={e} must be a multiple of "
-            f"block_e={block_e}: the grid tiles the queue evenly — go "
-            f"through the ops.py wrappers, which pad the queue for you")
-
-
-def _event_conv_interlaced_kernel(coords_ref, valid_ref, kernel_ref, vm_ref,
-                                  out_ref, *, block_e, event_par):
-    _apply_event_block_interlaced(coords_ref, valid_ref, kernel_ref, out_ref,
-                                  block_e=block_e, event_par=event_par)
+    _check_blocks(coords.shape[1], block_e)
+    return _conv_call(_sequential_kernel, vm_padded, coords, valid, kernel,
+                      block_e=block_e, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_e", "event_par", "interpret"))
@@ -325,7 +343,7 @@ def event_conv_pallas_interlaced(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Interlace-parallel ``event_conv_pallas``: ``event_par`` same-column
-    events per vectorized gather->add->scatter step.
+    events per gather->add->scatter step.
 
     Same contract as ``event_conv_pallas``; feed it interlace-ordered
     queues (ideally ``aeq.segment_pad``-ed, which makes every aligned
@@ -333,34 +351,10 @@ def event_conv_pallas_interlaced(
     Bit-exact vs the sequential kernel for float32/int16/int8
     (tests/test_interlaced.py).
     """
-    e = coords.shape[0]
-    _check_interlaced_blocks(e, block_e, event_par)
-    hp, wp, c = vm_padded.shape
-    kh, kw = kernel.shape[:2]
-    grid = (e // block_e,)
-    return pl.pallas_call(
-        partial(_event_conv_interlaced_kernel, block_e=block_e,
-                event_par=event_par),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, 2), lambda b: (b, 0)),
-            pl.BlockSpec((block_e,), lambda b: (b,)),
-            pl.BlockSpec((kh, kw, c), lambda b: (0, 0, 0)),
-            pl.BlockSpec((hp, wp, c), lambda b: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((hp, wp, c), lambda b: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((hp, wp, c), vm_padded.dtype),
-        input_output_aliases={3: 0},
-        interpret=resolve_interpret(interpret),
-    )(coords, valid.astype(jnp.int8), kernel, vm_padded)
-
-
-def _event_conv_interlaced_batched_kernel(coords_ref, valid_ref, kernel_ref,
-                                          vm_ref, out_ref, *, block_e,
-                                          event_par):
-    _apply_event_block_interlaced(coords_ref, valid_ref, kernel_ref, out_ref,
-                                  block_e=block_e, event_par=event_par,
-                                  prefix=(0,))
+    _check_interlaced_blocks(coords.shape[0], block_e, event_par)
+    return _conv_call(partial(_interlaced_kernel, event_par=event_par),
+                      vm_padded, coords, valid, kernel, block_e=block_e,
+                      interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_e", "event_par", "interpret"))
@@ -381,27 +375,7 @@ def event_conv_pallas_interlaced_batched(
     per-queue segment padding (``aeq.segment_pad``) keeps every group
     column-homogeneous.
     """
-    q, e, _ = coords.shape
-    _check_interlaced_blocks(e, block_e, event_par)
-    if vm_padded.shape[0] != q:
-        raise ValueError(
-            f"queue count mismatch: vm has {vm_padded.shape[0]} tiles, "
-            f"coords describe {q} queues")
-    _, hp, wp, c = vm_padded.shape
-    kh, kw = kernel.shape[:2]
-    grid = (q, e // block_e)
-    return pl.pallas_call(
-        partial(_event_conv_interlaced_batched_kernel, block_e=block_e,
-                event_par=event_par),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_e, 2), lambda qi, b: (qi, b, 0)),
-            pl.BlockSpec((1, block_e), lambda qi, b: (qi, b)),
-            pl.BlockSpec((kh, kw, c), lambda qi, b: (0, 0, 0)),
-            pl.BlockSpec((1, hp, wp, c), lambda qi, b: (qi, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hp, wp, c), lambda qi, b: (qi, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, hp, wp, c), vm_padded.dtype),
-        input_output_aliases={3: 0},
-        interpret=resolve_interpret(interpret),
-    )(coords, valid.astype(jnp.int8), kernel, vm_padded)
+    _check_interlaced_blocks(coords.shape[1], block_e, event_par)
+    return _conv_call(partial(_interlaced_kernel, event_par=event_par),
+                      vm_padded, coords, valid, kernel, block_e=block_e,
+                      interpret=interpret)

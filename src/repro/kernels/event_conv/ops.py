@@ -14,8 +14,8 @@ queue capacity and the VMEM budget instead of being hard-coded
 (``autotune_block_e`` / ``autotune_event_par``).
 
 Interpret mode is resolved centrally (``kernels.runtime``): pass
-``interpret=None`` (the default everywhere) and the REPRO_PALLAS_INTERPRET
-env var / backend default decides.
+``interpret=None`` (the default everywhere) and the backend decides —
+compiled on a TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 

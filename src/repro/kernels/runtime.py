@@ -1,48 +1,24 @@
 """Kernel runtime knobs shared by every Pallas wrapper in kernels/*.
 
-One switch decides whether Pallas kernels run in interpret mode (the
+One rule decides whether Pallas kernels run in interpret mode (the
 Mosaic interpreter, required off-TPU) or compiled (`interpret=False`, the
-real-TPU path).  Historically every call site defaulted to
-``interpret=True``, which meant validating on a real TPU required touching
-each wrapper; now they all default to ``interpret=None`` and resolve here:
+real-TPU path).  Every wrapper defaults to ``interpret=None`` and
+resolves here:
 
-* explicit ``interpret=`` argument wins (tests pin it);
-* else the ``REPRO_PALLAS_INTERPRET`` env var ("1"/"true"/"on" vs
-  "0"/"false"/"off") — the one-line flip for the ROADMAP real-TPU item;
-* else interpret is ON unless the default JAX backend is a TPU.
+* an explicit ``interpret=`` argument wins (tests pin it);
+* else interpret is ON exactly when the default JAX backend is not a TPU.
 
-Resolution happens at trace time (the flag is a static jit argument), so
-the env var is read the first time each wrapper traces a given shape;
-later calls with ``interpret=None`` hit the jit cache keyed on the same
-static ``None`` and do NOT re-read the env.  Treat the env var as a
-process-level launch flag (set it before the first kernel call, as the
-real-TPU validation flow does); to change modes within a live process,
-pass ``interpret=`` explicitly — the explicit value is part of the cache
-key, so it always takes effect.
+There is no process-level override: on a TPU backend an unpinned kernel
+always compiles, so nothing can silently fall back to the interpreter
+on the chip.  Resolution happens at trace time (the flag is a static jit
+argument), so an unpinned wrapper resolves once per traced shape.
 """
 from __future__ import annotations
-
-import os
-
-_TRUE = {"1", "true", "on", "yes"}
-_FALSE = {"0", "false", "off", "no"}
-
-INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
     """Resolve the effective interpret flag for a Pallas call."""
     if interpret is not None:
         return bool(interpret)
-    raw = os.environ.get(INTERPRET_ENV)
-    if raw is not None:
-        v = raw.strip().lower()
-        if v in _TRUE:
-            return True
-        if v in _FALSE:
-            return False
-        raise ValueError(
-            f"{INTERPRET_ENV}={raw!r} is not a boolean; use one of "
-            f"{sorted(_TRUE | _FALSE)}")
     import jax
     return jax.default_backend() != "tpu"

@@ -127,7 +127,11 @@ def run_cell(arch_id: str, shape_name: str, mesh_kind: str, out_dir: Path,
     from repro.train.optimizer import AdamWConfig
 
     shape = SHAPES[shape_name]
-    cell = {"arch": arch_id, "shape": shape_name, "mesh": mesh_kind, "tag": tag}
+    # the meshes are compiled on the host's virtual devices but stand for
+    # v5e pods: their roofline terms divide by the v5e peaks
+    target = rl.V5E
+    cell = {"arch": arch_id, "shape": shape_name, "mesh": mesh_kind, "tag": tag,
+            "device_kind": target}
     reason = skip_reason(arch_id, shape_name)
     if reason:
         cell.update(status="skipped", reason=reason)
@@ -165,7 +169,8 @@ def run_cell(arch_id: str, shape_name: str, mesh_kind: str, out_dir: Path,
             tokens = shape.global_batch * (shape.seq_len if shape.phase != "decode" else 1)
             mf = rl.model_flops_estimate(n_active, tokens, shape.phase)
             hlo_text = compiled.as_text()
-            roof = rl.analyze(compiled, n_dev, model_flops=mf, hlo_text=hlo_text)
+            roof = rl.analyze(compiled, n_dev, device_kind=target,
+                              model_flops=mf, hlo_text=hlo_text)
             if save_hlo:
                 import gzip
                 out_dir.mkdir(parents=True, exist_ok=True)

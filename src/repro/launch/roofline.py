@@ -14,8 +14,8 @@ reduce-scatter / all-to-all / collective-permute, converted to on-wire
 bytes with ring-algorithm multipliers (all-reduce 2(N-1)/N, all-gather
 (N-1)/N of the output, etc.).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI with 4 links/chip (2D torus: 2 axes x 2 directions).
+Hardware constants come from ``PEAKS``, keyed by the ``device_kind`` jax
+reports; a kind that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -24,10 +24,32 @@ import json
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
-LINKS_PER_CHIP = 4
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    bf16_flops: float   # FLOP/s, bf16 matmul
+    hbm_bw: float       # bytes/s, HBM
+    ici_bw: float       # bytes/s, all chip-to-chip links together
+
+
+V5E = "TPU v5 lite"     # jax's device_kind of a TPU v5e chip
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 (393 TOP/s
+    # int8), 819 GB/s HBM, 1,600 Gbit/s ICI
+    V5E: DevicePeaks(bf16_flops=197e12, hbm_bw=819e9, ici_bw=200e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; raises for a kind with no table entry."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak for {device_kind!r}: known kinds are "
+                         f"{sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -137,9 +159,12 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze(compiled, n_devices: int, model_flops: Optional[float] = None,
+def analyze(compiled, n_devices: int, *, device_kind: str,
+            model_flops: Optional[float] = None,
             hlo_text: Optional[str] = None) -> Roofline:
-    """Roofline terms from the compiled executable.
+    """Roofline terms from the compiled executable, against the peaks of
+    ``device_kind``: the chip the compiled mesh stands for, which the
+    caller names (a dry-run compiles on the host for a target it models).
 
     FLOPs/bytes/collectives come from launch/hlo_cost.py (HLO walk with
     while-trip-count multipliers) because XLA's HloCostAnalysis counts
@@ -152,9 +177,10 @@ def analyze(compiled, n_devices: int, model_flops: Optional[float] = None,
     text = hlo_text if hlo_text is not None else compiled.as_text()
     cost = HloCostModel(text, n_devices).entry_cost()
     flops, byts = cost.flops, cost.bytes
-    t_c = flops / PEAK_FLOPS
-    t_m = byts / HBM_BW
-    t_x = cost.wire / (LINKS_PER_CHIP * LINK_BW)
+    peaks = device_peaks(device_kind)
+    t_c = flops / peaks.bf16_flops
+    t_m = byts / peaks.hbm_bw
+    t_x = cost.wire / peaks.ici_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     try:

@@ -204,6 +204,8 @@ def main(argv=None):
                     help="print the NetworkPlan and per-layer event counts")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.arch in ("csnn-paper", "csnn-wide"):
         return serve_csnn(args)
 

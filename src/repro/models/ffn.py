@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 
 from .common import ParamSpec
 
@@ -194,8 +193,8 @@ def moe_forward_sharded(p: dict, x: jax.Array, *, top_k: int, n_experts: int,
                 P(expert_axis, None, None), P(expert_axis, None, None),
                 P(expert_axis, None, None))
     out_specs = (P(baxes if baxes else None, None, None), P())
-    out, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)(
+    out, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
         x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
     if "shared" in p:
         out = out + mlp_forward(p["shared"], x)

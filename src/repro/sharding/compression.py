@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 class CompressedGrad(NamedTuple):
@@ -90,7 +89,7 @@ def sparse_psum(c: CompressedGrad, mesh: Mesh, axis: str) -> jax.Array:
         dense = dense.at[all_idx.reshape(-1)].add(all_val.reshape(-1))
         return dense / n
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
         check_vma=False)(c.indices, c.values)
 

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 
 
 def psum_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str):
@@ -34,9 +33,9 @@ def psum_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str):
     def body(xs, ws):
         return jax.lax.psum(xs @ ws, axis)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(None, axis), P(axis, None)),
-                     out_specs=P())(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, axis), P(axis, None)),
+                         out_specs=P())(x, w)
 
 
 def ring_weight_gather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str):
@@ -69,10 +68,11 @@ def ring_weight_gather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str)
 
         acc0 = jnp.zeros((x_blk.shape[0], w_blk.shape[1]),
                          jnp.promote_types(x_blk.dtype, w_blk.dtype))
-        acc0 = pvary(acc0, (axis,))  # mark device-varying for the carry
+        # mark device-varying for the carry
+        acc0 = jax.lax.pcast(acc0, (axis,), to="varying")
         acc, _ = jax.lax.fori_loop(0, n, step, (acc0, w_blk))
         return acc.astype(x_blk.dtype)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axis, None), P(axis, None)),
-                     out_specs=P(axis, None))(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis, None), P(axis, None)),
+                         out_specs=P(axis, None))(x, w)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stacked_params, x: jax.Array, *,
@@ -87,8 +86,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x: jax.Array, *,
         return out.reshape(b, *x_all.shape[1:])
 
     in_specs = (P(axis), P())
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_vma=False)(stacked_params, x)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(stacked_params, x)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

@@ -133,7 +133,8 @@ def _measure_and_pick(cfg, base: dict, config: TuneConfig,
 
     conv_keys = [f"conv{lp.index}" for lp in plan0.layers]
     measured: dict[str, float] = {}
-    modelled: dict[str, float] = {}
+    modelled: dict[str, Optional[float]] = {}
+    kind = env["device_kind"]
 
     # -------- stage 1: per-layer (block_e, event_par, variant) ----------
     layer_winners = []
@@ -151,7 +152,7 @@ def _measure_and_pick(cfg, base: dict, config: TuneConfig,
                 lp_c, inputs[ci], p["w"], p["b"], cfg.v_t,
                 backend=config.backend, warmup=config.warmup,
                 iters=config.iters)
-            model_us = model_microseconds(hlo)
+            model_us = model_microseconds(hlo, kind)
             ranked.append((us, model_us, c, lp_c))
             measured[f"{lp.name}/{c.label()}"] = us
             modelled[f"{lp.name}/{c.label()}"] = model_us
@@ -187,7 +188,7 @@ def _measure_and_pick(cfg, base: dict, config: TuneConfig,
             params, x0, cfg, plan_c, backend=config.backend,
             warmup=config.warmup, iters=config.iters)
         measured[f"network/{label}"] = us
-        modelled[f"network/{label}"] = model_microseconds(hlo)
+        modelled[f"network/{label}"] = model_microseconds(hlo, kind)
         if best_us is None or us < best_us:
             best_net, best_us = nc, us
     log.info("tune[network]: winner per_layer=%s t_chunk=%s (%.1f us)",
@@ -234,7 +235,8 @@ def _measure_and_pick(cfg, base: dict, config: TuneConfig,
     entry = {"geometry": geom, "env": env, "winners": winners,
              "occupancy_capacities": occupancy,
              "measured_us": {k: round(v, 2) for k, v in measured.items()},
-             "model_us": {k: round(v, 2) for k, v in modelled.items()}}
+             "model_us": {k: round(v, 2) for k, v in modelled.items()
+                          if v is not None}}
     return final, entry
 
 

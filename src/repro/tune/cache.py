@@ -5,7 +5,8 @@ keyed by a digest of (layer geometry + planning knobs, vm dtype,
 requested backend, XLA backend, device kind, jax version) — the exact
 set of inputs that can change which schedule wins.  Location resolves
 ``cache_path`` arg > ``REPRO_PLAN_CACHE`` env var >
-``~/.cache/repro/plan_cache.json``.
+``<repo>/.plan_cache/plan_cache.json`` (git-ignored; nothing is written
+outside the checkout unless asked).
 
 Entries store the *winning knobs* (block_e / event_par / variant per
 layer, per_layer capacity sharing, t_chunk, stream_finalize), never a
@@ -38,12 +39,14 @@ from typing import Optional
 CACHE_VERSION = 3
 
 ENV_VAR = "REPRO_PLAN_CACHE"
-_DEFAULT = "~/.cache/repro/plan_cache.json"
+_DEFAULT = (Path(__file__).resolve().parents[3] / ".plan_cache"
+            / "plan_cache.json")
 
 
 def default_cache_path() -> Path:
-    """``REPRO_PLAN_CACHE`` override or the per-user default location."""
-    return Path(os.environ.get(ENV_VAR) or _DEFAULT).expanduser()
+    """``REPRO_PLAN_CACHE`` override or the in-checkout default location."""
+    env = os.environ.get(ENV_VAR)
+    return Path(env).expanduser() if env else _DEFAULT
 
 
 def geometry_descriptor(cfg, base: dict) -> dict:

@@ -3,7 +3,8 @@
 Contracts pinned here (serve/csnn_engine.py):
 
 * the continuous engine's per-request logits are bit-exact vs the
-  run-to-completion engine and vs the planned batched pipeline — slot
+  run-to-completion engine and match the planned batched pipeline (to
+  the stated logits tolerance where the batch shapes differ) — slot
   rows are per-sample independent, so a request sees the same T-step
   computation whichever slots its neighbours occupy;
 * requests admitted mid-flight (while other slots are mid-T-step) are
@@ -26,6 +27,7 @@ import pytest
 
 from repro.core import (CSNNConfig, ConvSpec, FCSpec, encode_input,
                         init_params, plan_network, snn_apply_batched)
+from repro.core.csnn import LOGITS_ATOL, LOGITS_RTOL
 from repro.serve.csnn_engine import CSNNEngine, CSNNServeConfig
 
 jax.config.update("jax_platform_name", "cpu")
@@ -63,7 +65,9 @@ class TestContinuousBitExact:
 
     def test_refill_preserves_per_request_logits(self):
         """Requests arriving while earlier ones are mid-T-step join free
-        slots (counted as refills) and still come back bit-exact.
+        slots (counted as refills) and still come back exact (to the
+        stated logits tolerance: the 7 requests ran on a 2-slot table,
+        the reference as one batch of 7).
 
         Deterministic staggering: the follow-up requests are submitted
         only once the first chunk is observed in flight (each chunk
@@ -86,7 +90,8 @@ class TestContinuousBitExact:
         got = np.stack(asyncio.run(staggered()))
         want = snn_apply_batched(params, encode_input(imgs, CFG), CFG, plan,
                                  collect_stats=False)
-        np.testing.assert_array_equal(got, np.asarray(want))
+        np.testing.assert_allclose(got, np.asarray(want), rtol=LOGITS_RTOL,
+                                   atol=LOGITS_ATOL)
         assert engine.stats["refills"] > 0
         assert engine.stats["admitted"] == engine.stats["retired"] == 7
 
@@ -124,7 +129,8 @@ class TestShutdownDrain:
             params, encode_input(imgs, CFG), CFG, plan, collect_stats=False))
         for i, f in enumerate(futs):
             if f.exception() is None:
-                np.testing.assert_array_equal(np.asarray(f.result()), want[i])
+                np.testing.assert_allclose(np.asarray(f.result()), want[i],
+                                           rtol=LOGITS_RTOL, atol=LOGITS_ATOL)
 
     def test_stop_flush_not_counted_as_deadline(self):
         """A stop-triggered partial flush increments flushes_stop, not

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_cost import HloCostModel, _shape_bytes, _shape_dims
+from repro.launch.mesh import make_custom_mesh
 from repro.sharding.specs import default_rules, resolve
 
 jax.config.update("jax_platform_name", "cpu")
@@ -36,26 +37,23 @@ def run_subprocess(code: str, devices: int = 8) -> str:
 # ---------------------------------------------------------------- rules
 class TestShardingRules:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_custom_mesh((1, 1), ("data", "model"))
 
     def test_resolve_drops_nondivisible(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_custom_mesh((1, 1), ("data", "model"))
         rules = default_rules()
         # 1-sized mesh axes divide everything -> axes assigned
         spec = resolve(("vocab", "embed"), (50304, 2560), rules, mesh)
         assert spec == jax.sharding.PartitionSpec("model", "data")
 
     def test_resolve_skips_missing_axes(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_custom_mesh((1, 1), ("data", "model"))
         rules = default_rules()  # fsdp = ("pod", "data"); no pod on this mesh
         spec = resolve(("embed",), (128,), rules, mesh)
         assert spec == jax.sharding.PartitionSpec("data")
 
     def test_resolve_no_axis_reuse(self):
-        try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-            mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
-        except TypeError:  # jax 0.4.x: tuple of (name, size) pairs
-            mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         rules = {"a": ("model",), "b": ("model",)}
         spec = resolve(("a", "b"), (4, 4), rules, mesh)
         # second use of "model" must be dropped
@@ -137,16 +135,17 @@ class TestMultiDevice:
         restore path (mesh-agnostic checkpoints)."""
         out = run_subprocess(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import ckpt
         tree = {{"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.arange(8.0)}}
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = {{"w": NamedSharding(mesh_a, P("data", "model")),
                 "b": NamedSharding(mesh_a, P("model"))}}
         sharded = jax.tree.map(jax.device_put, tree, sh_a)
         ckpt.save(sharded, r"{tmp_path}", step=1)
         for shape, axes in [((2, 4), ("data", "model")), ((8, 1), ("data", "model"))]:
-            mesh_b = jax.make_mesh(shape, axes)
+            mesh_b = make_mesh(shape, axes)
             sh_b = {{"w": NamedSharding(mesh_b, P("model", "data")),
                     "b": NamedSharding(mesh_b, P(None))}}
             restored, step = ckpt.restore(tree, r"{tmp_path}", shardings=sh_b)
@@ -161,8 +160,9 @@ class TestMultiDevice:
     def test_overlap_matmuls_correct(self):
         out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from repro.sharding.overlap import psum_matmul, ring_weight_gather_matmul
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(64, 24)).astype(np.float32))
@@ -178,10 +178,10 @@ class TestMultiDevice:
     def test_sparse_psum_matches_dense(self):
         out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.sharding.compression import compress_topk, decompress
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
 
         def body():
             i = jax.lax.axis_index("data").astype(jnp.float32)
@@ -193,8 +193,8 @@ class TestMultiDevice:
             ref = jax.lax.pmean(g, "data")
             return jnp.abs(dense - ref).max()
 
-        diff = shard_map(body, mesh=mesh, in_specs=(), out_specs=P(),
-                         check_vma=False)()
+        diff = jax.shard_map(body, mesh=mesh, in_specs=(), out_specs=P(),
+                             check_vma=False)()
         assert float(diff.max()) < 1e-6, float(diff.max())
         print("SPARSE_OK")
         """)
@@ -205,6 +205,7 @@ class TestMultiDevice:
         lower + compile + roofline extraction all work end to end."""
         out = run_subprocess("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from repro.configs import ARCHS
         from repro.configs.base import ShapeConfig
         from repro.launch import roofline as rl
@@ -212,7 +213,7 @@ class TestMultiDevice:
         from repro.sharding.specs import default_rules, tree_shardings, set_constraint_mesh
         from repro.train import optimizer as opt
 
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_mesh((4, 4), ("data", "model"))
         model = build_model(ARCHS["stablelm-3b"].SMOKE)
         shape = ShapeConfig("mini", 256, 8, "train")
         rules = default_rules()
@@ -237,7 +238,8 @@ class TestMultiDevice:
         fn = jax.jit(step, in_shardings=(st_sh, b_sh))
         with mesh:
             compiled = fn.lower(state, batch).compile()
-            roof = rl.analyze(compiled, 16, model_flops=1e9)
+            roof = rl.analyze(compiled, 16, device_kind=rl.V5E,
+                              model_flops=1e9)
         assert roof.flops_per_device > 0 and roof.bytes_per_device > 0
         assert roof.bottleneck in ("compute", "memory", "collective")
         print("DRYRUN_OK", roof.bottleneck)
@@ -249,8 +251,9 @@ class TestPipelineAndQuantizedCollectives:
     def test_pipeline_matches_sequential(self):
         out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from repro.sharding.pipeline import pipeline_apply, bubble_fraction
-        mesh = jax.make_mesh((4,), ("pp",))
+        mesh = make_mesh((4,), ("pp",))
         rng = np.random.default_rng(0)
         n_stages, d = 4, 16
         ws = jnp.asarray(rng.normal(size=(n_stages, d, d)).astype(np.float32) * 0.3)
@@ -274,10 +277,10 @@ class TestPipelineAndQuantizedCollectives:
     def test_quantized_pmean_unbiased(self):
         out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.sharding.compression import quantized_pmean
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
 
         def body(seed):
             g = jax.random.normal(jax.random.fold_in(
@@ -288,8 +291,9 @@ class TestPipelineAndQuantizedCollectives:
 
         errs = []
         for s in range(5):
-            e = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          check_vma=False)(jax.random.PRNGKey(s))
+            e = jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False)(
+                jax.random.PRNGKey(s))
             errs.append(float(e.max()))
         assert np.mean(errs) < 0.2, errs   # int8 noise, not bias
         print("QPMEAN_OK", [round(e, 3) for e in errs])
@@ -299,8 +303,9 @@ class TestPipelineAndQuantizedCollectives:
     def test_sharded_batcher(self):
         out = run_subprocess("""
         import jax, numpy as np
+        from repro.launch.mesh import make_custom_mesh as make_mesh
         from repro.data.synthetic import ShardedBatcher, TokenStream
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         b = ShardedBatcher(TokenStream(vocab=64, seed=0), 8, 16, mesh=mesh,
                            batch_axes=("data",))
         batch = b(step=3)

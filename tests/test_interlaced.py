@@ -39,7 +39,7 @@ from repro.kernels.event_conv import ops
 from repro.kernels.event_conv.kernel import (
     event_conv_pallas, event_conv_pallas_batched,
     event_conv_pallas_interlaced, event_conv_pallas_interlaced_batched)
-from repro.kernels.runtime import INTERPRET_ENV, resolve_interpret
+from repro.kernels.runtime import resolve_interpret
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -393,23 +393,17 @@ class TestPipelineBitExact:
 
 # ------------------------------------------------------------ interpret switch
 class TestInterpretSwitch:
-    def test_explicit_argument_wins(self, monkeypatch):
-        monkeypatch.setenv(INTERPRET_ENV, "0")
+    def test_explicit_argument_wins(self):
         assert resolve_interpret(True) is True
-        monkeypatch.setenv(INTERPRET_ENV, "1")
         assert resolve_interpret(False) is False
 
-    def test_env_var_controls_default(self, monkeypatch):
-        monkeypatch.setenv(INTERPRET_ENV, "off")
+    def test_explicit_wins_over_tpu_backend(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert resolve_interpret(True) is True
+
+    def test_tpu_backend_resolves_to_compiled(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert resolve_interpret() is False
-        monkeypatch.setenv(INTERPRET_ENV, "on")
-        assert resolve_interpret() is True
 
-    def test_backend_default_on_cpu(self, monkeypatch):
-        monkeypatch.delenv(INTERPRET_ENV, raising=False)
+    def test_backend_default_on_cpu(self):
         assert resolve_interpret() is True  # suite is CPU-pinned
-
-    def test_garbage_env_value_rejected(self, monkeypatch):
-        monkeypatch.setenv(INTERPRET_ENV, "maybe")
-        with pytest.raises(ValueError, match=INTERPRET_ENV):
-            resolve_interpret()

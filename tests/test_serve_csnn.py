@@ -3,8 +3,9 @@
 The engine's contract (serve/csnn_engine.py): requests flush when either
 ``max_batch`` are pending (size flush) or the oldest has waited
 ``max_delay_ms`` (deadline flush); partial batches pad to the plan's
-``batch_tile`` with zero images, and every request's logits are bit-exact
-vs running the batched pipeline directly on the un-padded requests.
+``batch_tile`` with zero images, and every request's logits match
+running the batched pipeline directly on the un-padded requests (to the
+stated logits tolerance, ``csnn.LOGITS_RTOL``/``LOGITS_ATOL``).
 """
 import asyncio
 
@@ -14,6 +15,7 @@ import numpy as np
 
 from repro.core import (CSNNConfig, ConvSpec, FCSpec, encode_input,
                         init_params, plan_network, snn_apply_batched)
+from repro.core.csnn import LOGITS_ATOL, LOGITS_RTOL
 from repro.serve.csnn_engine import CSNNEngine, CSNNServeConfig
 
 jax.config.update("jax_platform_name", "cpu")
@@ -72,12 +74,15 @@ class TestFlushSemantics:
 
     def test_logits_bit_exact_vs_direct_batched(self):
         """Engine results == running the planned batched pipeline directly
-        on the un-padded requests (zero-pad samples are independent)."""
+        on the un-padded requests (zero-pad samples are independent), to
+        the stated logits tolerance: the padded batch changes the head's
+        contraction shape, and with it the float reduction order."""
         params, plan, engine, imgs = _setup(n=3, max_batch=4, delay_ms=20.0)
         got = engine.run_requests(list(imgs))
         want = snn_apply_batched(params, encode_input(imgs, CFG), CFG, plan,
                                  collect_stats=False)
-        np.testing.assert_array_equal(got, np.asarray(want))
+        np.testing.assert_allclose(got, np.asarray(want), rtol=LOGITS_RTOL,
+                                   atol=LOGITS_ATOL)
 
     def test_multiple_waves_reuse_engine(self):
         params, plan, engine, imgs = _setup(n=4, max_batch=4)

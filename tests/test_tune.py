@@ -11,6 +11,7 @@ back to measuring; and tuned plans stay bit-exact vs analytic plans
 across dtypes, batching, and chunking.
 """
 import json
+from pathlib import Path
 from types import SimpleNamespace
 
 import jax
@@ -168,6 +169,33 @@ class TestCacheKey:
     def test_env_var_overrides_default_path(self, monkeypatch, tmp_path):
         monkeypatch.setenv("REPRO_PLAN_CACHE", str(tmp_path / "pc.json"))
         assert default_cache_path() == tmp_path / "pc.json"
+
+    def test_default_path_is_inside_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("REPRO_PLAN_CACHE", raising=False)
+        repo = Path(__file__).resolve().parents[1]
+        assert default_cache_path() == repo / ".plan_cache" / "plan_cache.json"
+
+
+# ------------------------------------------------ device peaks + crosscheck
+class TestDevicePeaks:
+    def test_unknown_kind_raises(self):
+        from repro.launch.roofline import device_peaks
+        with pytest.raises(ValueError, match="no peak for 'cpu'"):
+            device_peaks("cpu")
+
+    def test_v5e_entry(self):
+        from repro.launch.roofline import V5E, device_peaks
+        assert device_peaks(V5E).bf16_flops == 197e12
+        assert device_peaks(V5E).hbm_bw == 819e9
+
+    def test_no_model_time_without_a_peak(self, caplog):
+        from repro.tune.crosscheck import model_microseconds
+        with caplog.at_level("INFO", logger="repro.tune"):
+            assert model_microseconds("", "no such chip") is None
+            assert model_microseconds("", "no such chip") is None
+        assert [r.getMessage() for r in caplog.records].count(
+            "tune: no peak for no such chip; winners are ranked by "
+            "measurement alone, with no roofline cross-check") == 1
 
 
 # ------------------------------------------- plan-level variant validation
