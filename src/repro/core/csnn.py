@@ -141,9 +141,10 @@ def _max_pool(x: jax.Array, window: int) -> jax.Array:
 
 def encode_input(images: jax.Array, cfg: CSNNConfig) -> jax.Array:
     """(B, H, W, C) floats in [0,1] -> (B, T, H, W, C) m-TTFS input spikes."""
-    thresholds = mttfs_thresholds(cfg.t_steps)
-    enc = lambda img: multi_threshold_encode(img, thresholds, cfg.t_steps)
-    return jax.vmap(enc)(images)
+    with jax.named_scope("encode"):
+        thresholds = mttfs_thresholds(cfg.t_steps)
+        enc = lambda img: multi_threshold_encode(img, thresholds, cfg.t_steps)
+        return jax.vmap(enc)(images)
 
 
 def _resolve_plan(
@@ -186,14 +187,16 @@ def snn_apply(
     for idx, spec in enumerate(cfg.layers):
         if isinstance(spec, ConvSpec):
             p = params[f"conv{idx}"]
-            x, st = run_conv_layer_planned(x, p["w"], p["b"], cfg.v_t,
-                                           plan.layers[ci])
+            with jax.named_scope(f"conv{idx}"):
+                x, st = run_conv_layer_planned(x, p["w"], p["b"], cfg.v_t,
+                                               plan.layers[ci])
             stats.append(st)
             ci += 1
         else:
             p = params[f"fc{idx}"]
-            logits = run_fc_head(x, p["w"], p["b"],
-                                 capacity=plan.fc_capacity)
+            with jax.named_scope("head"):
+                logits = run_fc_head(x, p["w"], p["b"],
+                                     capacity=plan.fc_capacity)
     return (logits, stats) if collect_stats else logits
 
 
@@ -268,6 +271,12 @@ def snn_step_chunk(
     boundary and passed in place of the dense spike tensor, so the
     consumer never re-runs the dense->queue compaction pass.
 
+    Every op runs under a named scope of its unit (``jax.named_scope``):
+    ``conv{i}`` per conv layer, with ``compact``, ``conv_unit``,
+    ``threshold`` and ``handoff`` inside it, and ``head`` for the drive
+    into the classification unit, so a device trace names the unit of
+    each op.
+
     Returns ``state`` or ``(state, [chunk LayerStats, ...])`` with
     ``collect_stats``.
     """
@@ -275,8 +284,10 @@ def snn_step_chunk(
     n_conv = len(plan.layers)
     new_convs = []
     for idx, spec in enumerate(cfg.layers):
-        if isinstance(spec, ConvSpec):
-            p = params[f"conv{idx}"]
+        if not isinstance(spec, ConvSpec):
+            continue
+        p = params[f"conv{idx}"]
+        with jax.named_scope(f"conv{idx}"):
             if isinstance(x, StreamState):  # streamed input, layer 0 only
                 x, carry, st = run_conv_layer_batched_chunk_streamed(
                     x, p["w"], p["b"], cfg.v_t, plan.layers[ci],
@@ -291,9 +302,11 @@ def snn_step_chunk(
             if (ci < n_conv and plan.layers[ci].resolve_variant(backend)
                     == "fused-handoff"):
                 nxt = plan.layers[ci]
-                x = build_fused_handoff(x, nxt.capacity, nxt.geometry)
+                with jax.named_scope("handoff"):
+                    x = build_fused_handoff(x, nxt.capacity, nxt.geometry)
     b, c = x.shape[:2]
-    drive = x.reshape(b, c, -1).astype(state.fc_drive.dtype).sum(axis=1)
+    with jax.named_scope("head"):
+        drive = x.reshape(b, c, -1).astype(state.fc_drive.dtype).sum(axis=1)
     state = CSNNState(convs=tuple(new_convs),
                       fc_drive=state.fc_drive + drive)
     return (state, stats) if collect_stats else state
@@ -320,12 +333,14 @@ def snn_readout(params: dict, state: CSNNState, cfg: CSNNConfig,
         if not isinstance(spec, ConvSpec):
             p = params[f"fc{idx}"]
             drive = state.fc_drive
-            if fc_capacity is not None:
-                from .sparse_ffn import event_readout
-                logits = (event_readout(drive, p["w"], capacity=fc_capacity)
-                          + cfg.t_steps * p["b"])
-            else:
-                logits = head_dot(drive, p["w"]) + cfg.t_steps * p["b"]
+            with jax.named_scope("head"):
+                if fc_capacity is not None:
+                    from .sparse_ffn import event_readout
+                    logits = (event_readout(drive, p["w"],
+                                            capacity=fc_capacity)
+                              + cfg.t_steps * p["b"])
+                else:
+                    logits = head_dot(drive, p["w"]) + cfg.t_steps * p["b"]
     if logits is None:
         raise ValueError("cfg has no FC head layer")
     return logits
@@ -407,8 +422,10 @@ def _conv_stack_batched(params: dict, x: jax.Array, cfg: CSNNConfig,
     for idx, spec in enumerate(cfg.layers):
         if isinstance(spec, ConvSpec):
             p = params[f"conv{idx}"]
-            x, st = run_conv_layer_batched_planned(
-                x, p["w"], p["b"], cfg.v_t, plan.layers[ci], backend=backend)
+            with jax.named_scope(f"conv{idx}"):
+                x, st = run_conv_layer_batched_planned(
+                    x, p["w"], p["b"], cfg.v_t, plan.layers[ci],
+                    backend=backend)
             stats.append(st)
             ci += 1
     return x, stats
@@ -421,8 +438,9 @@ def _fc_head_batched(params: dict, x: jax.Array, cfg: CSNNConfig,
         if not isinstance(spec, ConvSpec):
             p = params[f"fc{idx}"]
             # last head wins, matching snn_apply's per-layer loop exactly
-            logits = run_fc_head_batched(x, p["w"], p["b"],
-                                         capacity=fc_capacity)
+            with jax.named_scope("head"):
+                logits = run_fc_head_batched(x, p["w"], p["b"],
+                                             capacity=fc_capacity)
     if logits is None:
         raise ValueError("cfg has no FC head layer")
     return logits

@@ -179,20 +179,23 @@ def run_conv_layer_planned(
     if fused:
         # fused spike-emission path: the padded centre-bank carrier IS the
         # consumable representation — no pre-shifted mask stack at all
-        ho = build_fused_handoff(spikes_in[None], lp.capacity, geom)
+        with jax.named_scope("handoff"):
+            ho = build_fused_handoff(spikes_in[None], lp.capacity, geom)
         smasks = ho.masks[:, :, 0]  # (T, C_in, n_banks, HB+2, WB+2)
         counts = ho.count[:, 0]     # (T, C_in)
     elif banked:
         # interlaced event-parallel path: sort-free bank-mask compaction,
         # write masks pre-shifted once and reused by every channel block
-        events = build_bank_masks(fmaps, lp.capacity, geom)
-        # (T, C_in, n_banks cols, n_banks banks, hb, wb)
-        smasks = shifted_bank_masks(events.masks, geom)
+        with jax.named_scope("compact"):
+            events = build_bank_masks(fmaps, lp.capacity, geom)
+            # (T, C_in, n_banks cols, n_banks banks, hb, wb)
+            smasks = shifted_bank_masks(events.masks, geom)
         counts = events.count
     else:
-        queues = build_aeq_batched(fmaps, lp.capacity, geometry=geom)
-        if lp.event_par > 1:
-            queues = segment_pad(queues, lp.event_par, geom)
+        with jax.named_scope("compact"):
+            queues = build_aeq_batched(fmaps, lp.capacity, geometry=geom)
+            if lp.event_par > 1:
+                queues = segment_pad(queues, lp.event_par, geom)
         counts = queues.count
 
     def run_block(kernel_block: jax.Array, bias_block: jax.Array) -> jax.Array:
@@ -240,16 +243,19 @@ def run_conv_layer_planned(
 
         def time_step(carry, t):
             vm, fired = carry
-            vm = apply_all_cins(vm, t)
-            inner = crop_vm(vm, geom)
+            with jax.named_scope("conv_unit"):
+                vm = apply_all_cins(vm, t)
 
             def thresh_one(v, f, b):
                 r = threshold_unit(v, b, v_t, f, pool=None, sat_bits=lp.sat_bits)
                 return r.v_m, r.fired, r.spikes
 
-            v_new, fired, spk = jax.vmap(thresh_one, in_axes=(2, 2, 0), out_axes=2)(
-                inner, fired, bias_block)
-            vm = vm.at[hh:h + hh, hw_:w + hw_, :].set(v_new)
+            with jax.named_scope("threshold"):
+                inner = crop_vm(vm, geom)
+                v_new, fired, spk = jax.vmap(
+                    thresh_one, in_axes=(2, 2, 0), out_axes=2)(
+                        inner, fired, bias_block)
+                vm = vm.at[hh:h + hh, hw_:w + hw_, :].set(v_new)
             return (vm, fired), spk
 
         (_, _), spikes = jax.lax.scan(time_step, (vm0, fired0), jnp.arange(t_steps))
@@ -271,7 +277,8 @@ def run_conv_layer_planned(
         event_par=jnp.asarray(lp.event_par, jnp.int32),
     )
     if lp.pool is not None:
-        return _pool_all(spikes_out, lp.pool), stats
+        with jax.named_scope("threshold"):
+            return _pool_all(spikes_out, lp.pool), stats
     return spikes_out, stats
 
 
@@ -434,7 +441,8 @@ def run_conv_layer_batched_chunk(
             # network edge (or unfused producer): build the carrier here —
             # same cost class as the banked compaction, still no
             # pre-shifted mask stack downstream
-            ho = build_fused_handoff(spikes_in, lp.capacity, lp.geometry)
+            with jax.named_scope("handoff"):
+                ho = build_fused_handoff(spikes_in, lp.capacity, lp.geometry)
         t_steps, c_in, b_sz = ho.masks.shape[:3]
         h, w = lp.in_hw
         # sparsity from the pre-truncation counts: 0/1 sums in f32 are
@@ -461,17 +469,20 @@ def run_conv_layer_batched_chunk(
         # amortization across channel blocks AND time steps is what pays
         # for the banked path (recomputing per step would cost more than
         # the conv work it saves on wide-C_in layers).
-        events = build_bank_masks(fmaps, lp.capacity, lp.geometry)
-        # (t, B, C_in, cols, banks, hb, wb) -> (t, C_in, B, ...) for
-        # scan + fori
-        queues = None
-        smasks = jnp.swapaxes(shifted_bank_masks(events.masks, lp.geometry),
-                              1, 2)
+        with jax.named_scope("compact"):
+            events = build_bank_masks(fmaps, lp.capacity, lp.geometry)
+            # (t, B, C_in, cols, banks, hb, wb) -> (t, C_in, B, ...) for
+            # scan + fori
+            queues = None
+            smasks = jnp.swapaxes(
+                shifted_bank_masks(events.masks, lp.geometry), 1, 2)
         counts = events.count
     else:
-        queues = build_aeq_batched(fmaps, lp.capacity, geometry=lp.geometry)
-        if lp.event_par > 1:
-            queues = segment_pad(queues, lp.event_par, lp.geometry)
+        with jax.named_scope("compact"):
+            queues = build_aeq_batched(fmaps, lp.capacity,
+                                       geometry=lp.geometry)
+            if lp.event_par > 1:
+                queues = segment_pad(queues, lp.event_par, lp.geometry)
         smasks, counts = None, queues.count
     sparsity = 1.0 - jnp.mean(spikes_in.astype(jnp.float32),
                               axis=(1, 2, 3, 4))
@@ -519,8 +530,9 @@ def run_conv_layer_batched_chunk_streamed(
     variant = lp.resolve_variant(backend)
     banked = variant == "banked-jax"
     if variant == "fused-handoff":
-        ho = fused_handoff_from_banks(stream.banks, lp.capacity, (h, w),
-                                      lp.geometry)
+        with jax.named_scope("handoff"):
+            ho = fused_handoff_from_banks(stream.banks, lp.capacity, (h, w),
+                                          lp.geometry)
         total = jnp.sum(ho.count.astype(jnp.float32), axis=(0, 2))
         sparsity = 1.0 - total / float(t_steps * h * w * c_in)
         return _run_chunk_from_events(
@@ -531,28 +543,31 @@ def run_conv_layer_batched_chunk_streamed(
     # stat; bank-mask/sort compaction input) — a reshape/transpose, no sort
     frames = stream_frames(stream, (h, w), lp.geometry)  # (B, t, C_in, H, W)
     if banked:
-        events = build_bank_masks(frames.transpose(1, 0, 2, 3, 4),
-                                  lp.capacity, lp.geometry)
-        queues = None
-        smasks = jnp.swapaxes(shifted_bank_masks(events.masks, lp.geometry),
-                              1, 2)
+        with jax.named_scope("compact"):
+            events = build_bank_masks(frames.transpose(1, 0, 2, 3, 4),
+                                      lp.capacity, lp.geometry)
+            queues = None
+            smasks = jnp.swapaxes(
+                shifted_bank_masks(events.masks, lp.geometry), 1, 2)
         counts = events.count
     else:
-        if lp.resolve_stream_finalize() == "sort":
-            # binned finalization: fused sort over the dense bank view,
-            # already in the (t, B, C_in) lead layout the launches index
-            queues = build_aeq_batched(frames.transpose(1, 0, 2, 3, 4),
-                                       lp.capacity, geometry=lp.geometry)
-        else:
-            queues = stream_queues(stream, lp.capacity, (h, w),
-                                   geometry=lp.geometry)
-            # (B, t, C_in, ...) -> (t, B, C_in, ...): the layout the
-            # per-(t, c_in) kernel launches below index
-            queues = BatchedEventQueue(*(None if x is None
-                                         else jnp.swapaxes(x, 0, 1)
-                                         for x in queues))
-        if lp.event_par > 1:
-            queues = segment_pad(queues, lp.event_par, lp.geometry)
+        with jax.named_scope("compact"):
+            if lp.resolve_stream_finalize() == "sort":
+                # binned finalization: fused sort over the dense bank
+                # view, already in the (t, B, C_in) lead layout the
+                # launches index
+                queues = build_aeq_batched(frames.transpose(1, 0, 2, 3, 4),
+                                           lp.capacity, geometry=lp.geometry)
+            else:
+                queues = stream_queues(stream, lp.capacity, (h, w),
+                                       geometry=lp.geometry)
+                # (B, t, C_in, ...) -> (t, B, C_in, ...): the layout the
+                # per-(t, c_in) kernel launches below index
+                queues = BatchedEventQueue(*(None if x is None
+                                             else jnp.swapaxes(x, 0, 1)
+                                             for x in queues))
+            if lp.event_par > 1:
+                queues = segment_pad(queues, lp.event_par, lp.geometry)
         smasks, counts = None, queues.count
     sparsity = 1.0 - jnp.mean(frames.astype(jnp.float32), axis=(1, 2, 3, 4))
     return _run_chunk_from_events(
@@ -638,17 +653,21 @@ def _run_chunk_from_events(
         def time_step(carry, xs):
             smasks_t, t = xs
             vm, fired = carry
-            vm = apply_all_cins(vm, smasks_t, t)
-            inner = vm[:, hh:h + hh, hw_:w + hw_, :]
+            with jax.named_scope("conv_unit"):
+                vm = apply_all_cins(vm, smasks_t, t)
 
             def thresh_one(v, f, b):
                 r = threshold_unit(v, b, v_t, f, pool=None, sat_bits=lp.sat_bits)
                 return r.v_m, r.fired, r.spikes
 
-            per_channel = jax.vmap(thresh_one, in_axes=(2, 2, 0), out_axes=2)
-            v_new, fired, spk = jax.vmap(per_channel, in_axes=(0, 0, None))(
-                inner, fired, bias_block)
-            vm = vm.at[:, hh:h + hh, hw_:w + hw_, :].set(v_new)
+            with jax.named_scope("threshold"):
+                inner = vm[:, hh:h + hh, hw_:w + hw_, :]
+                per_channel = jax.vmap(thresh_one, in_axes=(2, 2, 0),
+                                       out_axes=2)
+                v_new, fired, spk = jax.vmap(
+                    per_channel, in_axes=(0, 0, None))(
+                        inner, fired, bias_block)
+                vm = vm.at[:, hh:h + hh, hw_:w + hw_, :].set(v_new)
             return (vm, fired), spk
 
         xs = (smasks if (banked or fused)
@@ -680,7 +699,8 @@ def _run_chunk_from_events(
         event_par=jnp.asarray(lp.event_par, jnp.int32),
     )
     if lp.pool is not None:
-        return _pool_all(spikes_out, lp.pool), new_carry, stats
+        with jax.named_scope("threshold"):
+            return _pool_all(spikes_out, lp.pool), new_carry, stats
     return spikes_out, new_carry, stats
 
 

@@ -85,7 +85,8 @@ def serve_csnn(args) -> int:
         print(plan)
 
     if args.engine:
-        from repro.serve.csnn_engine import CSNNEngine, CSNNServeConfig
+        from repro.serve.csnn_engine import (CSNNEngine, CSNNServeConfig,
+                                             wait_quantile_ms)
         max_batch = -(-args.requests // batch_tile) * batch_tile
         engine = CSNNEngine(params, cfg, plan,
                             CSNNServeConfig(max_batch=max_batch,
@@ -102,11 +103,13 @@ def serve_csnn(args) -> int:
         dt = statistics.median(times)
         steady = f"{args.requests / dt:.1f} samples/s (median of {len(times)})"
         if args.continuous:
+            waits = engine.stats["admit_wait_hist"]
             extra = (f"engine: chunks={engine.stats['chunks']} "
                      f"admitted={engine.stats['admitted']} "
                      f"refills={engine.stats['refills']} "
                      f"slot_utilization={engine.slot_utilization:.0%} "
-                     f"wait_ms_max={engine.stats['wait_ms_max']:.1f} "
+                     f"wait_ms_p50={wait_quantile_ms(waits, 0.5):.1f} "
+                     f"wait_ms_p95={wait_quantile_ms(waits, 0.95):.1f} "
                      f"deadline_misses={engine.stats['deadline_misses']}")
             if args.stream:
                 extra += (f"\nstream: events={n_events} "

@@ -46,21 +46,46 @@ vs binning the same events into frames and serving those
 (tests/test_streaming.py).
 
 Every batch/chunk shape can be pre-compiled with ``warmup()`` so
-steady-state latency never includes a retrace.  Observability lives in
-``engine.stats`` (flush reasons, padded slots, chunk counts, slot
-occupancy, admission waits) — tests/test_serve_csnn.py pins the flush
-semantics, tests/test_continuous.py the refill semantics.
+steady-state latency never includes a retrace.
+
+Observability has three parts:
+
+* **host spans** (``jax.profiler.TraceAnnotation``, recorded only while
+  a profiler session runs): ``engine.submit`` (``rid=``) in
+  ``submit_nowait``; in the continuous loop ``engine.admit``,
+  ``engine.pack``, ``engine.dispatch``, ``engine.backlog``,
+  ``engine.wait``, ``engine.readout`` and ``engine.idle`` tile the loop
+  thread, and ``engine.encode`` (``rid=``) nests wherever a request is
+  encoded.  ``rid`` is the request's number, ``stats["requests"]`` at
+  its submit;
+* **device scopes** (``jax.named_scope``): ``engine.gather``,
+  ``engine.reset`` and ``engine.scatter`` in the chunk step, around the
+  model's own ``conv{i}/…``, ``head`` and ``encode`` scopes, so every
+  device op names its unit in the trace;
+* **counters** in ``engine.stats`` (flush reasons, padded slots, chunk
+  counts, slot occupancy, deadline misses) and ``admit_wait_hist``: a
+  cumulative histogram of admission wait, submit to slot, over the fixed
+  bucket edges ``ADMIT_WAIT_EDGES_MS`` (``wait_quantile_ms`` reads it).
+  Every value in ``stats`` is immutable, so ``dict(engine.stats)`` is a
+  snapshot, and two snapshots subtract into a window's counts.
+
+tests/test_serve_csnn.py pins the flush semantics,
+tests/test_continuous.py the refill semantics, tests/test_tracing.py the
+spans, scopes and histogram.
 """
 from __future__ import annotations
 
 import asyncio
+import bisect
 import dataclasses
+import math
 import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.aeq import StreamState
 from repro.core.csnn import (CSNNConfig, ConvSpec, encode_input, init_state,
@@ -69,6 +94,34 @@ from repro.core.plan import NetworkPlan, plan_network, snap_t_chunk
 from repro.data.dvs import events_to_banks
 
 _STOP = object()
+
+# Bucket edges of the admission-wait histogram, in ms: log-spaced from
+# 0.05 ms to 60 s, each bucket under 10% wide, so an interpolated
+# quantile is within 10% of the exact one.  Bucket 0 holds waits below
+# the first edge, bucket k waits in [edge[k-1], edge[k]), the last one
+# waits of 60 s or more.
+_N_WAIT_BUCKETS = math.ceil(math.log(60e3 / 0.05) / math.log(1.1))
+ADMIT_WAIT_EDGES_MS = tuple(
+    0.05 * (60e3 / 0.05) ** (k / _N_WAIT_BUCKETS)
+    for k in range(_N_WAIT_BUCKETS + 1))
+
+
+def wait_quantile_ms(counts, q: float) -> float:
+    """The ``q`` quantile (0 < q <= 1) of an admission-wait histogram
+    (``stats["admit_wait_hist"]``, or the difference of two), interpolated
+    linearly inside its bucket; NaN for an empty histogram.  A quantile
+    in the overflow bucket reads as its lower edge."""
+    total = sum(counts)
+    if total == 0:
+        return math.nan
+    rank, seen = q * total, 0
+    for k, n in enumerate(counts):
+        if n and seen + n >= rank:
+            if k == len(ADMIT_WAIT_EDGES_MS):
+                return ADMIT_WAIT_EDGES_MS[-1]
+            lo = ADMIT_WAIT_EDGES_MS[k - 1] if k else 0.0
+            return lo + (ADMIT_WAIT_EDGES_MS[k] - lo) * (rank - seen) / n
+        seen += n
 
 
 def _n_classes(cfg: CSNNConfig) -> int:
@@ -159,7 +212,8 @@ class CSNNEngine:
                       # continuous-mode slot table observability
                       "chunks": 0, "admitted": 0, "retired": 0, "refills": 0,
                       "slot_steps_busy": 0, "slot_steps_total": 0,
-                      "wait_ms_max": 0.0, "deadline_misses": 0}
+                      "deadline_misses": 0,
+                      "admit_wait_hist": (0,) * (_N_WAIT_BUCKETS + 2)}
         if serve_cfg.continuous:
             self._slots = serve_cfg.slots or serve_cfg.max_batch
             requested = serve_cfg.t_chunk or (
@@ -190,12 +244,16 @@ class CSNNEngine:
             # after every chunk, and the refill loop is dispatch-bound on
             # CPU, so the copies would cost more than the arithmetic.
             def step_bucket(state_full, idx, sp, admit_mask):
-                rows = jax.tree_util.tree_map(lambda l: l[idx], state_full)
-                rows = _reset_rows(rows, admit_mask)
+                with jax.named_scope("engine.gather"):
+                    rows = jax.tree_util.tree_map(lambda l: l[idx],
+                                                  state_full)
+                with jax.named_scope("engine.reset"):
+                    rows = _reset_rows(rows, admit_mask)
                 rows = snn_step_chunk(params, rows, sp, cfg, self.plan,
                                       backend=backend)
-                state_full = jax.tree_util.tree_map(
-                    lambda lf, lb: lf.at[idx].set(lb), state_full, rows)
+                with jax.named_scope("engine.scatter"):
+                    state_full = jax.tree_util.tree_map(
+                        lambda lf, lb: lf.at[idx].set(lb), state_full, rows)
                 # readout on the FULL slot table, not the bucket rows: the
                 # head contraction must keep one fixed (slots, D) shape —
                 # XLA's dot reduction order is shape-dependent, so a
@@ -283,12 +341,15 @@ class CSNNEngine:
         if self._flusher is not None and self._flusher.done():
             raise RuntimeError("engine flusher is not running (it stopped "
                                "or died); re-enter the context manager")
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
-        self._inflight.add(fut)
-        fut.add_done_callback(self._inflight.discard)
-        self._queue.put_nowait((jnp.asarray(image), fut, loop.time()))
-        self.stats["requests"] += 1
+        rid = self.stats["requests"]
+        with TraceAnnotation("engine.submit", rid=rid):
+            loop = asyncio.get_running_loop()
+            fut = loop.create_future()
+            self._inflight.add(fut)
+            fut.add_done_callback(self._inflight.discard)
+            self._queue.put_nowait((jnp.asarray(image), fut, loop.time(),
+                                    rid))
+            self.stats["requests"] += 1
         return fut
 
     async def submit(self, image) -> np.ndarray:
@@ -400,7 +461,7 @@ class CSNNEngine:
 
         def encoded(item):
             """Lazily encode a pending entry in place: [spk|None, img,
-            fut, arrived].  The backlog is encoded in the window right
+            fut, arrived, rid].  The backlog is encoded in the window right
             after a chunk dispatch (host work concurrent with the
             device's async-dispatched execution); an entry admitted
             before that window pays its encode here, on demand.
@@ -410,13 +471,15 @@ class CSNNEngine:
             the (T, C, 9, HB, WB) interlace-column banks — a single
             vectorized numpy assignment per request."""
             if item[0] is None:
-                if stream:
-                    item[0] = events_to_banks(
-                        np.asarray(item[1]), T, (h, w), c, geometry=geom)
-                else:
-                    item[0] = np.asarray(
-                        self._encode(jnp.asarray(item[1])[None])[0],
-                        dtype=bool)
+                with TraceAnnotation("engine.encode", rid=item[4]):
+                    if stream:
+                        item[0] = events_to_banks(
+                            np.asarray(item[1]), T, (h, w), c,
+                            geometry=geom)
+                    else:
+                        item[0] = np.asarray(
+                            self._encode(jnp.asarray(item[1])[None])[0],
+                            dtype=bool)
             return item[0]
 
         def drain_nowait():
@@ -429,98 +492,113 @@ class CSNNEngine:
                 if item is _STOP:
                     stop_seen = True
                 else:
-                    img, fut, arrived = item
-                    pending.append([None, img, fut, arrived])
+                    pending.append([None, *item])
 
+        # Each phase of a round is one span, and the spans tile the loop
+        # thread: whatever the thread does between chunks is named.
         while True:
-            drain_nowait()
-            # ---- admission: refill free slots; re-zero their state rows
-            midflight = any(active[j] and slot_t[j] > 0 for j in range(S))
-            admit = np.zeros(S, dtype=bool)
-            now = loop.time()
-            for i in range(S):
-                if active[i] or not pending:
-                    continue
-                entry = pending.pop(0)
-                spk = encoded(entry)
-                _, _, fut, arrived = entry
-                slot_spk[i], slot_t[i], slot_fut[i] = spk, 0, fut
-                active[i], admit[i] = True, True
-                wait_ms = (now - arrived) * 1e3
-                self.stats["admitted"] += 1
-                self.stats["wait_ms_max"] = max(self.stats["wait_ms_max"],
-                                                wait_ms)
-                if wait_ms > self.serve_cfg.max_delay_ms:
-                    self.stats["deadline_misses"] += 1
-                if midflight:  # joined while others are mid-T-step: a refill
-                    self.stats["refills"] += 1
-            n_active = sum(active)
-            if n_active == 0:
-                if stop_seen and not pending:
+            with TraceAnnotation("engine.admit"):
+                drain_nowait()
+                # ---- admission: refill free slots; re-zero their rows
+                midflight = any(active[j] and slot_t[j] > 0
+                                for j in range(S))
+                admit = np.zeros(S, dtype=bool)
+                now = loop.time()
+                waits = []
+                for i in range(S):
+                    if active[i] or not pending:
+                        continue
+                    entry = pending.pop(0)
+                    spk = encoded(entry)
+                    fut, arrived = entry[2], entry[3]
+                    slot_spk[i], slot_t[i], slot_fut[i] = spk, 0, fut
+                    active[i], admit[i] = True, True
+                    waits.append((now - arrived) * 1e3)
+                    if midflight:  # joined while others are mid-T-step
+                        self.stats["refills"] += 1
+                if waits:
+                    hist = list(self.stats["admit_wait_hist"])
+                    for wait_ms in waits:
+                        hist[bisect.bisect_right(ADMIT_WAIT_EDGES_MS,
+                                                 wait_ms)] += 1
+                        if wait_ms > self.serve_cfg.max_delay_ms:
+                            self.stats["deadline_misses"] += 1
+                    self.stats["admit_wait_hist"] = tuple(hist)
+                    self.stats["admitted"] += len(waits)
+                n_active = sum(active)
+                if n_active == 0 and stop_seen and not pending:
                     drain_nowait()  # serve submits racing __aexit__, like
                     if not pending:  # the micro-batching drain does
                         break
                     continue
-                item = await self._queue.get()  # idle: wait for work or stop
-                if item is _STOP:
-                    stop_seen = True
-                else:
-                    img, fut, arrived = item
-                    pending.append([None, img, fut, arrived])
+            if n_active == 0:
+                with TraceAnnotation("engine.idle"):  # wait for work or stop
+                    item = await self._queue.get()
+                    if item is _STOP:
+                        stop_seen = True
+                    else:
+                        pending.append([None, *item])
                 continue
-            # ---- advance the active slots by one chunk, packed into the
-            # smallest compiled occupancy bucket (pad rows carry idx == S:
-            # clamped on gather, dropped on scatter)
-            act = [i for i in range(S) if active[i]]
-            b = next(bb for bb in self._buckets if bb >= n_active)
-            idx = np.full(b, S, dtype=np.int32)
-            chunk = np.zeros(
-                (b, tc, c, geom.n_banks, -(-h // geom.kh), -(-w // geom.kw))
-                if stream else (b, tc, h, w, c), dtype=bool)
-            admit_b = np.zeros(b, dtype=bool)
-            for j, i in enumerate(act):
-                idx[j] = i
-                chunk[j] = slot_spk[i][slot_t[i]:slot_t[i] + tc]
-                admit_b[j] = admit[i]
-            # fused gather + admit-reset + chunk step + readout + scatter,
-            # async dispatch
-            sp = jnp.asarray(chunk)
-            if stream:
-                sp = StreamState(banks=sp)
-            state, logits_dev = self._step(state, idx, sp, admit_b)
-            self.stats["chunks"] += 1
-            self.stats["slot_steps_busy"] += n_active
-            self.stats["slot_steps_total"] += b
-            # ---- overlap: encode the waiting backlog on this thread while
-            # the async-dispatched chunk executes on the device ...
-            drain_nowait()
-            for entry in pending:
-                encoded(entry)
-            # ... then pace the loop to the device from a worker thread so
-            # the event loop keeps accepting submits during the chunk
-            # (blocking here on the loop thread would batch admissions
-            # into lockstep waves — the refill would be refill in name
-            # only)
-            await asyncio.to_thread(jax.block_until_ready, logits_dev)
-            # ---- retire finished slots (the only device sync point)
-            finished = []
-            for i in range(S):
-                if active[i]:
-                    slot_t[i] += tc
-                    if slot_t[i] >= T:
-                        finished.append(i)
-            if finished:
-                logits = np.asarray(logits_dev)  # (S, n_classes), slot-indexed
-                for i in finished:
-                    if not slot_fut[i].done():
-                        slot_fut[i].set_result(logits[i])
-                    active[i] = False
-                    slot_fut[i] = slot_spk[i] = None
-                    self.stats["retired"] += 1
+            with TraceAnnotation("engine.pack"):
+                # ---- advance the active slots by one chunk, packed into
+                # the smallest compiled occupancy bucket (pad rows carry
+                # idx == S: clamped on gather, dropped on scatter)
+                act = [i for i in range(S) if active[i]]
+                b = next(bb for bb in self._buckets if bb >= n_active)
+                idx = np.full(b, S, dtype=np.int32)
+                chunk = np.zeros(
+                    (b, tc, c, geom.n_banks, -(-h // geom.kh),
+                     -(-w // geom.kw))
+                    if stream else (b, tc, h, w, c), dtype=bool)
+                admit_b = np.zeros(b, dtype=bool)
+                for j, i in enumerate(act):
+                    idx[j] = i
+                    chunk[j] = slot_spk[i][slot_t[i]:slot_t[i] + tc]
+                    admit_b[j] = admit[i]
+                sp = jnp.asarray(chunk)
+                if stream:
+                    sp = StreamState(banks=sp)
+            with TraceAnnotation("engine.dispatch"):
+                # fused gather + admit-reset + chunk step + readout +
+                # scatter, async dispatch
+                state, logits_dev = self._step(state, idx, sp, admit_b)
+                self.stats["chunks"] += 1
+                self.stats["slot_steps_busy"] += n_active
+                self.stats["slot_steps_total"] += b
+            with TraceAnnotation("engine.backlog"):
+                # ---- overlap: encode the waiting backlog on this thread
+                # while the async-dispatched chunk executes on the device ...
+                drain_nowait()
+                for entry in pending:
+                    encoded(entry)
+            with TraceAnnotation("engine.wait"):
+                # ... then pace the loop to the device from a worker thread
+                # so the event loop keeps accepting submits during the
+                # chunk (blocking here on the loop thread would batch
+                # admissions into lockstep waves — the refill would be
+                # refill in name only)
+                await asyncio.to_thread(jax.block_until_ready, logits_dev)
+            with TraceAnnotation("engine.readout"):
+                # ---- retire finished slots (the only device sync point)
+                finished = []
+                for i in range(S):
+                    if active[i]:
+                        slot_t[i] += tc
+                        if slot_t[i] >= T:
+                            finished.append(i)
+                if finished:
+                    # (S, n_classes), slot-indexed
+                    logits = np.asarray(logits_dev)
+                    for i in finished:
+                        if not slot_fut[i].done():
+                            slot_fut[i].set_result(logits[i])
+                        active[i] = False
+                        slot_fut[i] = slot_spk[i] = None
+                        self.stats["retired"] += 1
         # Failsafe: anything that slipped in after the final drain check is
         # failed explicitly so no future ever hangs (the drain above makes
         # this window practically unreachable).
         drain_nowait()
-        for _, _, fut, _ in pending:
+        for _, _, fut, *_ in pending:
             if not fut.done():
                 fut.set_exception(RuntimeError("engine stopped"))
