@@ -102,7 +102,8 @@ def main(json_out: bool = False):
     # column application per (t, c_in, bank).  Bit-exact vs the batched
     # row (asserted) and the headline speedup of the interlaced refactor.
     plan_il = plan_network(cfg, capacity=cap, channel_block=8,
-                           batch_tile=batch, event_par=None)
+                           batch_tile=batch, event_par=None,
+                           variant="banked-jax")
     il_fn = jax.jit(lambda s: snn_apply_batched(
         params, s, cfg, plan_il, collect_stats=False))
     assert np.array_equal(np.asarray(il_fn(spikes)),

@@ -29,7 +29,9 @@ Sizing rules (all static, all pure functions of geometry + calibration):
   Fig. 6 cashed in): 1 keeps the sequential one-event-at-a-time conv
   unit; > 1 selects the interlace-aware kernel variants, which apply
   same-column (hazard-free) events in parallel — the banked-select jax
-  path and the ``event_conv_pallas_interlaced*`` kernels.  ``None``
+  path and the ``event_conv_pallas_interlaced*`` kernels — or, on
+  lossless float32 layers of the jax backend, the dense MXU conv
+  (``"dense-mxu"``, :meth:`LayerPlan.runs_dense`).  ``None``
   autotunes it next to ``block_e`` (``autotune_event_par``: snapped to a
   power of two, VMEM-aware, floored to 1 when queues are too shallow to
   pay for parallelism).  When > 1, ``block_e`` is additionally snapped to
@@ -40,8 +42,9 @@ Every rule only ever *lowers* the effective queue depth to the point
 where nothing can be dropped (or keeps the requested truncation depth),
 so planned execution is bit-exact vs the legacy shared-capacity kwargs —
 the deprecation shims in scheduler.py/csnn.py rely on this; the
-``event_par`` variants are bit-exact vs the sequential schedule by the
-interlace disjointness argument (tests/test_interlaced.py).
+interlaced event variants are bit-exact vs the sequential schedule by the
+interlace disjointness argument (tests/test_interlaced.py), and the dense
+MXU conv matches them to float rounding (tests/test_dense_mxu.py).
 """
 from __future__ import annotations
 
@@ -75,8 +78,14 @@ _VM_DTYPES = {None: "float32", 8: "int8", 16: "int16"}
 # lets the measured autotuner (repro.tune) pick per layer.
 # "fused-handoff" only runs when pinned: resolve_variant never
 # auto-selects it, because it changes the *inter*-layer dataflow.
+# "dense-mxu" runs the conv unit as one SAME convolution per chunk on the
+# MXU (no compaction, no per-event work); resolve_variant picks it in
+# place of "banked-jax" where that variant would do dense-cost work and
+# the dense result is the same one (LayerPlan.runs_dense).  It sums the
+# same float32 products in another order, so it matches the event
+# variants to float rounding, not bit for bit.
 KERNEL_VARIANTS = ("sequential", "banked-jax", "interlaced-pallas",
-                   "fused-handoff")
+                   "fused-handoff", "dense-mxu")
 
 # Streaming-ingestion finalization variants (input layer only): "ranks"
 # is the sort-free exclusive-cumulative-rank path (aeq.stream_queues);
@@ -168,17 +177,38 @@ class LayerPlan:
         """Effective kernel variant for this layer under ``backend``.
 
         A pinned :attr:`variant` wins (the measured autotuner's choice);
-        otherwise the legacy rule applies: ``event_par > 1`` selects the
-        interlaced machinery (Pallas kernels on the pallas backend, the
-        banked-select jax path elsewhere), ``event_par == 1`` the
-        sequential conv unit.
+        otherwise ``event_par > 1`` selects the interlaced machinery
+        (Pallas kernels on the pallas backend; on the jax backend the
+        dense MXU conv where :meth:`runs_dense` holds, else the
+        banked-select path), ``event_par == 1`` the sequential conv unit.
         """
         if self.variant is not None:
             return self.variant
+        if self.runs_dense(backend):
+            return "dense-mxu"
         if self.event_par > 1:
             return ("interlaced-pallas" if backend == "pallas"
                     else "banked-jax")
         return "sequential"
+
+    def runs_dense(self, backend: str = "jax") -> bool:
+        """Whether an unpinned layer takes ``"dense-mxu"`` under ``backend``.
+
+        The banked jax path costs k²·H·W·C_out element operations per
+        input channel and step whatever the spike count, so it never
+        beats one dense convolution on the MXU; the dense conv then runs
+        wherever it gives the event path's result: an unpinned
+        ``event_par > 1`` layer on the jax backend whose queue is
+        lossless (``capacity >= H·W``: a truncating queue drops events,
+        a dense conv would not), with the float32 datapath (int
+        saturation is applied per event and has no dense equivalent),
+        and that ingests no raw stream (a streamed input layer keeps its
+        banks).
+        """
+        h, w = self.in_hw
+        return (self.variant is None and backend == "jax"
+                and self.event_par > 1 and self.capacity >= h * w
+                and self.sat_bits is None and self.ingest_capacity is None)
 
     def resolve_stream_finalize(self) -> str:
         """Effective streamed-queue finalization for this (input) layer.
@@ -219,7 +249,9 @@ class LayerPlan:
         par = f", par={self.event_par}" if self.event_par > 1 else ""
         ing = (f", ingest={self.ingest_capacity}x{self.ingest_depth}"
                if self.ingest_capacity is not None else "")
-        var = f", variant={self.variant}" if self.variant is not None else ""
+        var = (f", variant={self.variant} (pinned)"
+               if self.variant is not None
+               else f", variant={self.resolve_variant()}")
         fin = (f", finalize={self.stream_finalize}"
                if self.stream_finalize is not None else "")
         geo = ("" if self.geometry == GEOM_3X3
@@ -412,6 +444,12 @@ def plan_conv_layer(
             f"variant='interlaced-pallas' requires event_par > 1 (got "
             f"{ep}): the interlaced kernel walks event_par-aligned groups "
             f"of the segment-padded queue")
+    if variant == "dense-mxu" and (cap < h * w or sat_bits is not None):
+        raise ValueError(
+            f"variant='dense-mxu' requires a lossless float32 layer "
+            f"(capacity={cap} >= {h * w} cells, sat_bits=None; got "
+            f"sat_bits={sat_bits}): a dense conv neither drops events "
+            f"nor saturates per event")
     if stream_finalize is not None and stream_finalize not in STREAM_FINALIZE:
         raise ValueError(f"stream_finalize={stream_finalize!r} must be one "
                          f"of {STREAM_FINALIZE} (or None = resolve by fmap "
@@ -480,10 +518,11 @@ def plan_network(
 
     ``variant`` pins the kernel variant per layer (one of
     :data:`KERNEL_VARIANTS`, single value or one per conv layer; ``None``
-    keeps the legacy event_par/backend resolution) and
-    ``stream_finalize`` the streamed-queue finalization of the ingesting
-    input layer (:data:`STREAM_FINALIZE`) — both are pure perf knobs,
-    bit-exact across every setting.
+    keeps the event_par/backend resolution, ``LayerPlan.resolve_variant``)
+    and ``stream_finalize`` the streamed-queue finalization of the
+    ingesting input layer (:data:`STREAM_FINALIZE`) — both are pure perf
+    knobs, bit-exact across every setting but ``"dense-mxu"``, which
+    matches to float rounding.
 
     ``fc_capacity`` opts the classification head into the event-driven
     sparse readout (``sparse_ffn.event_readout``): the accumulated FC

@@ -55,10 +55,15 @@ precedence; otherwise ``event_par`` + backend decide):
   (``event_conv.apply_banked_columns_fused``) — no deinterlace, no dense
   intermediate, no second compaction pass, and no pre-shifted 81-mask
   stack (the slices alias one padded carrier).
+* ``"dense-mxu"`` — the conv unit as one SAME convolution over every
+  frame of the chunk at ``Precision.HIGHEST`` (``_run_chunk_dense``): no
+  compaction, no channel blocks, no per-``c_in`` loop; the per-step scan
+  only accumulates and thresholds.  Chosen in place of ``banked-jax`` on
+  lossless float32 layers (``LayerPlan.runs_dense``).
 
-All variants are bit-exact vs the sequential schedule
-(tests/test_interlaced.py); the choice is a pure perf knob, which is
-what lets ``repro.tune`` pick the measured winner per layer.
+The event variants are bit-exact vs the sequential schedule
+(tests/test_interlaced.py); ``dense-mxu`` sums the same products in
+another order and matches them to float rounding (tests/test_dense_mxu.py).
 """
 from __future__ import annotations
 
@@ -171,6 +176,14 @@ def run_conv_layer_planned(
     channel_block = lp.channel_block
     vm_dtype = lp.vm_dtype if vm_dtype is None else vm_dtype
     variant = lp.resolve_variant(backend)
+    if variant == "dense-mxu":  # a single sample is a batch of one
+        spikes_out, _, stats = _run_chunk_dense(
+            spikes_in[None], kernels, bias, v_t, lp,
+            init_conv_carry(lp, 1, vm_dtype), vm_dtype=vm_dtype)
+        return spikes_out[0], stats._replace(
+            in_spike_counts=stats.in_spike_counts[0],
+            out_spike_counts=stats.out_spike_counts[0],
+            in_sparsity=stats.in_sparsity[0])
     banked = variant == "banked-jax"
     fused = variant == "fused-handoff"
     geom = lp.geometry
@@ -455,6 +468,9 @@ def run_conv_layer_batched_chunk(
             None, ho.masks, ho.count, sparsity,
             (b_sz, t_steps, h, w, c_in), kernels, bias, v_t, lp, carry,
             variant=variant, backend=backend, vm_dtype=vm_dtype)
+    if variant == "dense-mxu":
+        return _run_chunk_dense(spikes_in, kernels, bias, v_t, lp, carry,
+                                vm_dtype=vm_dtype)
     b_sz, t_steps, h, w, c_in = spikes_in.shape
     banked = variant == "banked-jax"
     # (B, t, H, W, C_in) -> per-(t, b, c_in) event sets, built in one pass
@@ -542,6 +558,9 @@ def run_conv_layer_batched_chunk_streamed(
     # dense view only where the binned path itself is dense (sparsity
     # stat; bank-mask/sort compaction input) — a reshape/transpose, no sort
     frames = stream_frames(stream, (h, w), lp.geometry)  # (B, t, C_in, H, W)
+    if variant == "dense-mxu":  # only when pinned: see LayerPlan.runs_dense
+        return _run_chunk_dense(frames.transpose(0, 1, 3, 4, 2), kernels,
+                                bias, v_t, lp, carry, vm_dtype=vm_dtype)
     if banked:
         with jax.named_scope("compact"):
             events = build_bank_masks(frames.transpose(1, 0, 2, 3, 4),
@@ -574,6 +593,76 @@ def run_conv_layer_batched_chunk_streamed(
         queues, smasks, counts, sparsity, (b_sz, t_steps, h, w, c_in),
         kernels, bias, v_t, lp, carry, variant=variant, backend=backend,
         vm_dtype=vm_dtype)
+
+
+def _run_chunk_dense(
+    spikes_in: jax.Array,
+    kernels: jax.Array,
+    bias: jax.Array,
+    v_t,
+    lp: LayerPlan,
+    carry: ConvCarry,
+    *,
+    vm_dtype=None,
+) -> tuple[jax.Array, ConvCarry, LayerStats]:
+    """Chunk body of the ``"dense-mxu"`` variant, with the signature and
+    results of :func:`run_conv_layer_batched_chunk`.
+
+    The conv unit's input for the whole chunk is on hand and does not
+    depend on the layer's state, so it runs as ONE SAME convolution over
+    all B·t_chunk frames, every C_in into every C_out, at
+    ``Precision.HIGHEST`` (float32 on the MXU).  The scan over t only
+    accumulates and thresholds, ``V += u_t`` then bias, ``> v_t`` and the
+    m-TTFS latch, over every channel at once — the order of the dense
+    oracle (``run_conv_layer_dense``).  The carry keeps its halo-padded
+    layout; the halo is never written.  ``in_spike_counts`` are the
+    spike sums over (H, W), equal to the event path's queue counts.
+    """
+    b_sz, t_steps, h, w, c_in = spikes_in.shape
+    c_out = kernels.shape[-1]
+    vm_dtype = lp.vm_dtype if vm_dtype is None else vm_dtype
+    hh, hw_ = lp.geometry.halo
+    with jax.named_scope("conv_unit"):
+        x = jnp.swapaxes(spikes_in, 0, 1).reshape(t_steps * b_sz, h, w, c_in)
+        u = jax.lax.conv_general_dilated(
+            x.astype(vm_dtype), kernels.astype(vm_dtype), (1, 1), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)
+        # (W, C_out) flattened onto the minor axis: the per-step
+        # elementwise work stays lane-dense whatever C_out is
+        u = u.reshape(t_steps, b_sz, h, w * c_out)
+
+    bias_wc = jnp.tile(bias.astype(vm_dtype), w)  # channel c at w*C_out + c
+
+    def time_step(c, u_t):
+        vm, fired = c
+        with jax.named_scope("threshold"):
+            r = threshold_unit(vm + u_t, bias_wc, v_t, fired, pool=None,
+                               sat_bits=lp.sat_bits)
+        return (r.v_m, r.fired), r.spikes
+
+    vm0 = carry.vm.astype(vm_dtype)
+    inner = vm0[:, hh:h + hh, hw_:w + hw_, :].reshape(b_sz, h, w * c_out)
+    fired0 = carry.fired.reshape(b_sz, h, w * c_out)
+    (vm, fired), spikes = jax.lax.scan(time_step, (inner, fired0), u)
+    new_carry = ConvCarry(
+        vm=vm0.at[:, hh:h + hh, hw_:w + hw_, :].set(
+            vm.reshape(b_sz, h, w, c_out)),
+        fired=fired.reshape(b_sz, h, w, c_out))
+    spikes_out = jnp.swapaxes(spikes, 0, 1).reshape(b_sz, t_steps, h, w,
+                                                    c_out)
+    stats = LayerStats(
+        in_spike_counts=jnp.sum(spikes_in, axis=(2, 3), dtype=jnp.int32),
+        out_spike_counts=jnp.sum(spikes_out, axis=(2, 3)).astype(jnp.int32),
+        in_sparsity=1.0 - jnp.mean(spikes_in.astype(jnp.float32),
+                                   axis=(1, 2, 3, 4)),
+        event_block=jnp.asarray(lp.block_e, jnp.int32),
+        event_par=jnp.asarray(lp.event_par, jnp.int32),
+    )
+    if lp.pool is not None:
+        with jax.named_scope("threshold"):
+            return _pool_all(spikes_out, lp.pool), new_carry, stats
+    return spikes_out, new_carry, stats
 
 
 def _run_chunk_from_events(

@@ -167,7 +167,7 @@ class TestWideEndToEnd:
              < 0.4).astype(jnp.float32)
         spikes = encode_input(x, cfg)
         plan = plan_network(cfg, capacity=h * w, channel_block=4,
-                            event_par=None)
+                            event_par=None, variant="banked-jax")
         assert plan.layers[0].geometry.window == (5, 5)
         assert plan.layers[0].geometry.n_banks == 25
         assert plan.layers[1].geometry == GEOM_3X3  # mixed-geometry net

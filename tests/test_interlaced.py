@@ -353,7 +353,7 @@ class TestPipelineBitExact:
             rng.random((3, 10, 10, 1)), jnp.float32), SMOKE)
         seq = plan_network(SMOKE, capacity=100, sat_bits=sat_bits)
         par = plan_network(SMOKE, capacity=100, sat_bits=sat_bits,
-                           event_par=4)
+                           event_par=4, variant="banked-jax")
         a, sa = snn_apply_batched(params, sp, SMOKE, seq)
         b, sb = snn_apply_batched(params, sp, SMOKE, par)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -368,8 +368,10 @@ class TestPipelineBitExact:
         params = init_params(jax.random.PRNGKey(1), SMOKE)
         sp = encode_input(jnp.asarray(
             rng.random((2, 10, 10, 1)), jnp.float32), SMOKE)
-        plan = plan_network(SMOKE, capacity=100, event_par=4, t_chunk=2)
-        whole = plan_network(SMOKE, capacity=100, event_par=4)
+        plan = plan_network(SMOKE, capacity=100, event_par=4, t_chunk=2,
+                            variant="banked-jax")
+        whole = plan_network(SMOKE, capacity=100, event_par=4,
+                             variant="banked-jax")
         a = snn_apply_batched(params, sp, SMOKE, whole, collect_stats=False)
         state = init_state(params, SMOKE, plan, 2)
         for k in range(0, SMOKE.t_steps, plan.chunk_steps):
@@ -383,7 +385,8 @@ class TestPipelineBitExact:
         params = init_params(jax.random.PRNGKey(2), SMOKE)
         sp = encode_input(jnp.asarray(
             rng.random((3, 10, 10, 1)), jnp.float32), SMOKE)
-        plan = plan_network(SMOKE, capacity=100, event_par=4)
+        plan = plan_network(SMOKE, capacity=100, event_par=4,
+                            variant="banked-jax")
         from repro.core.csnn import snn_apply
         a = jax.vmap(lambda s: snn_apply(params, s, SMOKE, plan,
                                          collect_stats=False))(sp)

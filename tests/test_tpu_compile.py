@@ -15,13 +15,17 @@ the fixture loads the library in one worker only.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import csnn_paper
-from repro.core.csnn import init_params, snn_apply_batched
+from repro.core.aeq import StreamState
+from repro.core.csnn import (encode_input, init_params, init_state,
+                             snn_apply_batched, snn_readout, snn_step_chunk)
 from repro.core.plan import plan_network
 from repro.kernels.event_conv import kernel as ec
 from repro.kernels.threshold_pool.kernel import threshold_pool_pallas
@@ -122,7 +126,8 @@ def test_threshold_pool_compiles(one_chip, layer, emit):
 
 def test_served_batched_step_compiles(one_chip):
     """The jitted serving step: ``snn_apply_batched`` over the whole
-    paper network at B=64 under the autotuned (banked) plan."""
+    paper network at B=64 under the autotuned plan (``dense-mxu`` conv
+    units: its queues are lossless)."""
     plan = served_plan(None)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))
     h, w = CFG.input_hw
@@ -137,3 +142,59 @@ def test_served_batched_step_compiles(one_chip):
     compiled = compile_for(one_chip, step, spikes, *leaves)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 * 2**30   # fits next to the weights
+
+
+def _flat_params(cfg):
+    """(parameter shapes, their leaves, leaves -> parameter tree)."""
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    return params, leaves, lambda flat: jax.tree_util.tree_unflatten(tree,
+                                                                     flat)
+
+
+def test_dense_mxu_offline_call_compiles(one_chip):
+    """The offline call at B=256, T=5 (encode + ``snn_apply_batched``):
+    every conv layer runs its conv unit as one ``dense-mxu`` convolution
+    per chunk."""
+    batch = 256
+    plan = plan_network(CFG, capacity=784, channel_block=8,
+                        batch_tile=batch, event_par=None)
+    assert {lp.resolve_variant() for lp in plan.layers} == {"dense-mxu"}
+    _, leaves, unflatten = _flat_params(CFG)
+    images = jax.ShapeDtypeStruct((batch, 28, 28, 1), jnp.float32)
+
+    def call(x, *flat):
+        return snn_apply_batched(unflatten(flat), encode_input(x, CFG), CFG,
+                                 plan, collect_stats=False)
+
+    compiled = compile_for(one_chip, call, images, *leaves)
+    assert "convolution" in compiled.as_text()
+
+
+def test_dense_mxu_serve_bucket_compiles(one_chip):
+    """One chunk step of the stream engine's plan at t_chunk=1 and an
+    occupancy bucket of 16: the streamed conv0 keeps its banks, conv1
+    and conv2 take ``dense-mxu``."""
+    cfg = dataclasses.replace(CFG, input_channels=2)
+    bucket = 16
+    plan = plan_network(cfg, capacity=784, channel_block=8, batch_tile=64,
+                        event_par=None, ingest=True)
+    assert [lp.resolve_variant() for lp in plan.layers] == [
+        "banked-jax", "dense-mxu", "dense-mxu"]
+    params, leaves, unflatten = _flat_params(cfg)
+    geom = plan.layers[0].geometry
+    banks = jax.ShapeDtypeStruct(
+        (bucket, 1, 2, geom.n_banks, -(-28 // geom.kh), -(-28 // geom.kw)),
+        jnp.bool_)
+    state = jax.eval_shape(lambda p: init_state(p, cfg, plan, bucket),
+                           params)
+    s_leaves, s_tree = jax.tree_util.tree_flatten(state)
+    n = len(s_leaves)
+
+    def step(b, *flat):
+        st = jax.tree_util.tree_unflatten(s_tree, flat[:n])
+        out = snn_step_chunk(unflatten(flat[n:]), st, StreamState(banks=b),
+                             cfg, plan)
+        return snn_readout(unflatten(flat[n:]), out, cfg)
+
+    compile_for(one_chip, step, banks, *s_leaves, *leaves)

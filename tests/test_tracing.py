@@ -184,24 +184,39 @@ def _has(paths, layer, unit):
                for p in paths)
 
 
-def _expect_conv_units(paths, n_conv, units=("compact", "conv_unit",
-                                             "threshold")):
-    for i in range(n_conv):
-        for unit in units:
-            assert _has(paths, f"conv{i}", unit), (i, unit, sorted(paths))
+# the units each variant's conv layer runs: the dense conv and the fused
+# handoff consume their input without a compaction pass
+UNITS = {"dense-mxu": ("conv_unit", "threshold"),
+         "fused-handoff": ("conv_unit", "threshold")}
 
 
-@pytest.mark.parametrize("variant", [None, "fused-handoff"])
+def _expect_conv_units(paths, plan):
+    """Each conv layer's scopes hold the units of its resolved variant,
+    and a ``dense-mxu`` layer compacts nothing."""
+    for lp in plan.layers:
+        variant = lp.resolve_variant()
+        for unit in UNITS.get(variant, ("compact", "conv_unit",
+                                        "threshold")):
+            assert _has(paths, lp.name, unit), (lp.name, variant, unit,
+                                                sorted(paths))
+        if variant == "dense-mxu":
+            assert not _has(paths, lp.name, "compact"), (lp.name,
+                                                         sorted(paths))
+
+
+@pytest.mark.parametrize("variant", [None, "fused-handoff", "banked-jax"])
 def test_chunk_step_scopes(variant):
     _, params, plan = _engine(variant=variant)
     chunk = StreamState(banks=jnp.zeros((2, 1, 2, 9, 4, 4), jnp.bool_))
     state = init_state(params, CFG, plan, 2)
     fn = jax.jit(lambda st, sp: snn_step_chunk(params, st, sp, CFG, plan))
     paths = _scopes(fn.lower(state, chunk).compile().as_text())
-    if variant is None:
-        _expect_conv_units(paths, 2)
-    else:  # conv0 builds its own queues and conv1's as handoff carriers
-        _expect_conv_units(paths, 2, ("conv_unit", "threshold"))
+    _expect_conv_units(paths, plan)
+    if variant is None:  # the streamed conv0 keeps its banks
+        assert [lp.resolve_variant() for lp in plan.layers] == [
+            "banked-jax", "dense-mxu"]
+    elif variant == "fused-handoff":
+        # conv0 builds its own queues and conv1's as handoff carriers
         assert _has(paths, "conv0", "handoff")
     assert "head" in paths
 
@@ -217,7 +232,10 @@ def test_offline_call_scopes():
         p, encode_input(x, cfg), cfg, plan, collect_stats=False))
     paths = _scopes(fn.lower(params, jnp.zeros((2, 12, 12, 1)))
                     .compile().as_text())
-    _expect_conv_units(paths, 3)
+    # the 4x4 conv2's queue is too shallow for event parallelism
+    assert [lp.resolve_variant() for lp in plan.layers] == [
+        "dense-mxu", "dense-mxu", "sequential"]
+    _expect_conv_units(paths, plan)
     assert {"encode", "head"} <= paths
 
 
@@ -228,7 +246,7 @@ def test_bucket_step_scopes():
     chunk = StreamState(banks=jnp.zeros((2, 1, 2, 9, 4, 4), jnp.bool_))
     paths = _scopes(engine._step.lower(state, idx, chunk,
                                        np.zeros(2, bool)).compile().as_text())
-    _expect_conv_units(paths, 2)
+    _expect_conv_units(paths, plan)
     assert {"engine.gather", "engine.reset", "engine.scatter",
             "head"} <= paths
 
